@@ -1,11 +1,4 @@
-"""Version-compatibility shims for the JAX API surface we depend on.
-
-The repo targets the modern ``jax.shard_map`` entry point (with its
-``check_vma`` argument); older releases only ship
-``jax.experimental.shard_map.shard_map`` (whose equivalent flag is
-``check_rep``). Every shard_map call site goes through
-:func:`shard_map_compat` so the SPMD engines run on either API.
-"""
+"""The one ``shard_map`` entry point every SPMD call site uses."""
 
 from __future__ import annotations
 
@@ -17,19 +10,7 @@ import jax
 def shard_map_compat(
     f: Callable, *, mesh: Any, in_specs: Any, out_specs: Any
 ) -> Callable:
-    """``jax.shard_map`` with replication checking off, on any jax version.
-
-    Tries the public ``jax.shard_map`` (new API, ``check_vma=``) first and
-    falls back to ``jax.experimental.shard_map.shard_map`` (old API,
-    ``check_rep=``). Both flags disable the same static replication check,
-    which our device functions fail structurally (axis-dependent slicing).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # type: ignore
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """``jax.shard_map`` with the static replication check off: the device
+    functions slice by ``axis_index``, which that check cannot follow."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)

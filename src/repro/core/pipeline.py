@@ -318,38 +318,24 @@ def input_specs(scfg: SpmdConfig):
 
 def _score_chunk_update(scfg: SpmdConfig, x_c, xn2_c, qrows, qn2, acc, tau):
     """One (group, chunk, block) partial update — Pallas or jnp ref."""
-    if scfg.use_pallas:
-        out, skip = kops.partial_distance_update(
-            x_c, xn2_c, qrows, qn2, acc, tau,
-            prune=scfg.prune, metric=scfg.metric,
-            tile_m=scfg.tile_m, tile_n=scfg.tile_n, tile_k=scfg.tile_k,
-        )
-        return out, skip.sum(), skip.size
-    from repro.kernels import ref
-
-    out = ref.partial_distance_update_ref(
-        x_c, xn2_c, qrows, qn2, acc, tau, prune=scfg.prune, metric=scfg.metric
+    out, skip = kops.partial_distance_update(
+        x_c, xn2_c, qrows, qn2, acc, tau,
+        prune=scfg.prune, metric=scfg.metric,
+        tile_m=scfg.tile_m, tile_n=scfg.tile_n, tile_k=scfg.tile_k,
+        use_pallas=scfg.use_pallas,
     )
-    skip = kops._tile_skip_map(acc, scfg.tile_m, scfg.tile_n)
     return out, skip.sum(), skip.size
 
 
 def _score_chunk_update_int8(scfg: SpmdConfig, x_c, xn2_c, qrows, qn2, s2,
                              acc, tau):
     """int8 variant: codes in, int32 MXU accumulation, f32 combine."""
-    if scfg.use_pallas:
-        out, skip = kops.int8_partial_distance_update(
-            x_c, xn2_c, qrows, qn2, s2, acc, tau,
-            prune=scfg.prune,
-            tile_m=scfg.tile_m, tile_n=scfg.tile_n, tile_k=scfg.tile_k,
-        )
-        return out, skip.sum(), skip.size
-    from repro.kernels import ref
-
-    out = ref.int8_partial_distance_update_ref(
-        x_c, xn2_c, qrows, qn2, s2, acc, tau, prune=scfg.prune
+    out, skip = kops.int8_partial_distance_update(
+        x_c, xn2_c, qrows, qn2, s2, acc, tau,
+        prune=scfg.prune,
+        tile_m=scfg.tile_m, tile_n=scfg.tile_n, tile_k=scfg.tile_k,
+        use_pallas=scfg.use_pallas,
     )
-    skip = kops._tile_skip_map(acc, scfg.tile_m, scfg.tile_n)
     return out, skip.sum(), skip.size
 
 

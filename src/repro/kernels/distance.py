@@ -6,12 +6,16 @@ distance computation"), adapted to the TPU memory hierarchy:
 * the [M, N] accumulator tile, the [bm, bk]/[bn, bk] operand tiles, and the
   per-row norm/threshold vectors live in VMEM via ``BlockSpec``;
 * the partial distance is computed on the MXU as
-  ``acc + ‖p‖²_b − 2·Q@Xᵀ + ‖q‖²_b`` with f32 accumulation;
+  ``acc + ‖p‖²_b − 2·Q@Xᵀ + ‖q‖²_b`` with f32 accumulation at full f32
+  contraction precision (the engine's results must equal the exact
+  oracle's, so the MXU may not round operands to bf16);
 * **tile-granular early-stop**: if every pair in the [bm, bn] accumulator
   tile is already pruned (+inf), the MXU matmul for this tile is skipped
   via ``pl.when`` — the TPU-native replacement for the paper's per-element
-  CPU branch. A per-tile skip map is emitted so benchmarks can report the
-  realized compute saving.
+  CPU branch. The per-tile skip map is computed from the accumulator
+  before the call and handed to the kernel as a scalar-prefetch operand
+  in SMEM, so the branch reads one scalar; the same map is returned so
+  benchmarks can report the realized compute saving.
 
 Grid: (m_tiles, n_tiles, k_chunks); the k axis is minor-most so the output
 tile is revisited across the contraction and stays resident in VMEM.
@@ -20,14 +24,16 @@ tile is revisited across the contraction and stays resident in VMEM.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(
+    live_ref,   # SMEM [m_tiles * n_tiles] int32, 1 = tile has a live pair
     x_ref,      # [bn, bk]
     xn2_ref,    # [1, bn]
     q_ref,      # [bm, bk]
@@ -35,16 +41,15 @@ def _kernel(
     acc_ref,    # [bm, bn]
     tau_ref,    # [bm, 1]
     out_ref,    # [bm, bn]
-    skip_ref,   # [1, 1] int32 per-tile skip marker
     *,
+    nn: int,
     nk: int,
     prune: bool,
     metric: str,
 ):
-    k = pl.program_id(2)
+    i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     acc_in = acc_ref[...]
     alive = jnp.isfinite(acc_in)
-    any_alive = jnp.any(alive)
 
     @pl.when(k == 0)
     def _init():
@@ -54,9 +59,8 @@ def _kernel(
         else:
             base = acc_in
         out_ref[...] = jnp.where(alive, base, jnp.inf)
-        skip_ref[0, 0] = jnp.where(any_alive, 0, 1).astype(jnp.int32)
 
-    @pl.when(any_alive)
+    @pl.when(live_ref[i * nn + j] > 0)
     def _matmul():
         xf = x_ref[...].astype(jnp.float32)
         qf = q_ref[...].astype(jnp.float32)
@@ -64,6 +68,7 @@ def _kernel(
             qf,
             xf,
             (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
         scale = 2.0 if metric == "l2" else 1.0
@@ -77,7 +82,8 @@ def _kernel(
         out_ref[...] = out
 
 
-def _pad_to(a: jnp.ndarray, mult: Tuple[int, ...], value) -> jnp.ndarray:
+def pad_to(a: jnp.ndarray, mult: Tuple[int, ...], value) -> jnp.ndarray:
+    """Pad each axis of ``a`` up to a multiple of ``mult`` with ``value``."""
     pads = []
     for dim, m in zip(a.shape, mult):
         rem = (-dim) % m
@@ -85,6 +91,37 @@ def _pad_to(a: jnp.ndarray, mult: Tuple[int, ...], value) -> jnp.ndarray:
     if any(p[1] for p in pads):
         a = jnp.pad(a, pads, constant_values=value)
     return a
+
+
+def tile_skip_map(acc: jnp.ndarray, tile_m: int, tile_n: int) -> jnp.ndarray:
+    """Which [tile_m, tile_n] tiles of ``acc`` are fully pruned (+inf):
+    [m_tiles, n_tiles] int32, 1 = the tile's matmul is skipped."""
+    m, n = acc.shape
+    a = pad_to(acc, (tile_m, tile_n), jnp.float32(jnp.inf))
+    a = a.reshape(a.shape[0] // tile_m, tile_m, a.shape[1] // tile_n, tile_n)
+    alive = jnp.isfinite(a).any(axis=(1, 3))
+    return (~alive).astype(jnp.int32)
+
+
+def tiled_distance_call(kernel, operands, specs, *, skip, tile_m, tile_n,
+                        tile_k, dp, interpret):
+    """``pallas_call`` over the (m_tiles, n_tiles, k_chunks) grid shared by
+    the fp32 and int8 kernels. ``operands``/``specs`` are the kernel's
+    tensor inputs and their ``BlockSpec``s; the live-tile map (1 − skip)
+    goes first, as the scalar-prefetch operand."""
+    nm, nn = skip.shape
+    out_spec = pl.BlockSpec((tile_m, tile_n), lambda i, j, k, live: (i, j))
+    return pl.pallas_call(
+        functools.partial(kernel, nn=nn, nk=dp // tile_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nm, nn, dp // tile_k),
+            in_specs=specs,
+            out_specs=out_spec,
+        ),
+        out_shape=jax.ShapeDtypeStruct((nm * tile_m, nn * tile_n), jnp.float32),
+        interpret=interpret,
+    )((1 - skip).reshape(-1), *operands)
 
 
 @functools.partial(
@@ -107,42 +144,29 @@ def partial_distance_update(
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (acc' [M, N] f32, tile_skipped [m_tiles, n_tiles] int32)."""
-    m, n, d = q.shape[0], x.shape[0], x.shape[1]
+    m, n = q.shape[0], x.shape[0]
     # Pad to tile multiples. Padded candidate rows get acc=+inf (excluded);
     # padded query rows get tau=-inf so everything in them prunes away.
-    xp = _pad_to(x, (tile_n, tile_k), 0)
-    qp = _pad_to(q, (tile_m, tile_k), 0)
-    xn2p = _pad_to(xn2.reshape(1, -1), (1, tile_n), 0)
-    qn2p = _pad_to(qn2.reshape(-1, 1), (tile_m, 1), 0)
-    taup = _pad_to(tau.reshape(-1, 1), (tile_m, 1), jnp.float32(-jnp.inf))
-    accp = jnp.pad(
-        acc,
-        ((0, (-m) % tile_m), (0, (-n) % tile_n)),
-        constant_values=jnp.float32(jnp.inf),
-    )
-    mp, np_ = accp.shape
-    dp = xp.shape[1]
-    nm, nn, nk = mp // tile_m, np_ // tile_n, dp // tile_k
+    xp = pad_to(x, (tile_n, tile_k), 0)
+    qp = pad_to(q, (tile_m, tile_k), 0)
+    xn2p = pad_to(xn2.reshape(1, -1), (1, tile_n), 0)
+    qn2p = pad_to(qn2.reshape(-1, 1), (tile_m, 1), 0)
+    taup = pad_to(tau.reshape(-1, 1), (tile_m, 1), jnp.float32(-jnp.inf))
+    accp = pad_to(acc, (tile_m, tile_n), jnp.float32(jnp.inf))
+    skip = tile_skip_map(accp, tile_m, tile_n)
 
-    out, skip = pl.pallas_call(
-        functools.partial(_kernel, nk=nk, prune=prune, metric=metric),
-        grid=(nm, nn, nk),
-        in_specs=[
-            pl.BlockSpec((tile_n, tile_k), lambda i, j, k: (j, k)),   # x
-            pl.BlockSpec((1, tile_n), lambda i, j, k: (0, j)),        # xn2
-            pl.BlockSpec((tile_m, tile_k), lambda i, j, k: (i, k)),   # q
-            pl.BlockSpec((tile_m, 1), lambda i, j, k: (i, 0)),        # qn2
-            pl.BlockSpec((tile_m, tile_n), lambda i, j, k: (i, j)),   # acc
-            pl.BlockSpec((tile_m, 1), lambda i, j, k: (i, 0)),        # tau
+    out = tiled_distance_call(
+        functools.partial(_kernel, prune=prune, metric=metric),
+        (xp, xn2p, qp, qn2p, accp, taup),
+        [
+            pl.BlockSpec((tile_n, tile_k), lambda i, j, k, live: (j, k)),   # x
+            pl.BlockSpec((1, tile_n), lambda i, j, k, live: (0, j)),        # xn2
+            pl.BlockSpec((tile_m, tile_k), lambda i, j, k, live: (i, k)),   # q
+            pl.BlockSpec((tile_m, 1), lambda i, j, k, live: (i, 0)),        # qn2
+            pl.BlockSpec((tile_m, tile_n), lambda i, j, k, live: (i, j)),   # acc
+            pl.BlockSpec((tile_m, 1), lambda i, j, k, live: (i, 0)),        # tau
         ],
-        out_specs=[
-            pl.BlockSpec((tile_m, tile_n), lambda i, j, k: (i, j)),   # out
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),             # skip map
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-            jax.ShapeDtypeStruct((nm, nn), jnp.int32),
-        ],
-        interpret=interpret,
-    )(xp, xn2p, qp, qn2p, accp, taup)
+        skip=skip, tile_m=tile_m, tile_n=tile_n, tile_k=tile_k,
+        dp=xp.shape[1], interpret=interpret,
+    )
     return out[:m, :n], skip

@@ -12,10 +12,11 @@ terms in f32; only the Q·P term runs on the MXU, as a pure int8×int8
 matmul with ``preferred_element_type=jnp.int32`` (no f32 casts of the
 operands — the 4× narrower codes are what the MXU reads from VMEM).
 
-Everything else mirrors ``distance.py``: same grid (m_tiles, n_tiles,
-k_chunks) with k minor-most, same +inf/−inf padding conventions, same
-tile-granular ``pl.when`` early-stop with a per-tile skip map. L2 only —
-the quantized difference form has no inner-product analogue here.
+Everything else is ``distance.py``'s: the same ``pallas_call`` over the
+grid (m_tiles, n_tiles, k_chunks) with k minor-most, the same +inf/−inf
+padding conventions, the same tile-granular ``pl.when`` early-stop on a
+scalar-prefetched skip map. The shared s² is read as an SMEM scalar. L2
+only — the quantized difference form has no inner-product analogue here.
 """
 
 from __future__ import annotations
@@ -26,34 +27,36 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.distance import pad_to, tile_skip_map, tiled_distance_call
 
 
 def _kernel(
+    live_ref,   # SMEM [m_tiles * n_tiles] int32, 1 = tile has a live pair
     x_ref,      # [bn, bk] int8 corpus codes
     xn2_ref,    # [1, bn]  f32, s²·ΣP² for this dimension block
     q_ref,      # [bm, bk] int8 query codes
     qn2_ref,    # [bm, 1]  f32, s²·ΣQ² for this dimension block
-    s2_ref,     # [1, 1]   f32, s² shared by corpus and query
+    s2_ref,     # SMEM [1, 1] f32, s² shared by corpus and query
     acc_ref,    # [bm, bn]
     tau_ref,    # [bm, 1]
     out_ref,    # [bm, bn]
-    skip_ref,   # [1, 1] int32 per-tile skip marker
     *,
+    nn: int,
     nk: int,
     prune: bool,
 ):
-    k = pl.program_id(2)
+    i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     acc_in = acc_ref[...]
     alive = jnp.isfinite(acc_in)
-    any_alive = jnp.any(alive)
 
     @pl.when(k == 0)
     def _init():
         base = acc_in + qn2_ref[...] + xn2_ref[...]
         out_ref[...] = jnp.where(alive, base, jnp.inf)
-        skip_ref[0, 0] = jnp.where(any_alive, 0, 1).astype(jnp.int32)
 
-    @pl.when(any_alive)
+    @pl.when(live_ref[i * nn + j] > 0)
     def _matmul():
         dot = jax.lax.dot_general(
             q_ref[...],
@@ -71,16 +74,6 @@ def _kernel(
         if prune:
             out = jnp.where(out > tau_ref[...], jnp.inf, out)
         out_ref[...] = out
-
-
-def _pad_to(a: jnp.ndarray, mult: Tuple[int, ...], value) -> jnp.ndarray:
-    pads = []
-    for dim, m in zip(a.shape, mult):
-        rem = (-dim) % m
-        pads.append((0, rem))
-    if any(p[1] for p in pads):
-        a = jnp.pad(a, pads, constant_values=value)
-    return a
 
 
 @functools.partial(
@@ -121,41 +114,28 @@ def int8_partial_distance_update(
     5.0
     """
     m, n = q.shape[0], x.shape[0]
-    xp = _pad_to(x, (tile_n, tile_k), 0)
-    qp = _pad_to(q, (tile_m, tile_k), 0)
-    xn2p = _pad_to(xn2.reshape(1, -1), (1, tile_n), 0)
-    qn2p = _pad_to(qn2.reshape(-1, 1), (tile_m, 1), 0)
-    taup = _pad_to(tau.reshape(-1, 1), (tile_m, 1), jnp.float32(-jnp.inf))
-    accp = jnp.pad(
-        acc,
-        ((0, (-m) % tile_m), (0, (-n) % tile_n)),
-        constant_values=jnp.float32(jnp.inf),
-    )
+    xp = pad_to(x, (tile_n, tile_k), 0)
+    qp = pad_to(q, (tile_m, tile_k), 0)
+    xn2p = pad_to(xn2.reshape(1, -1), (1, tile_n), 0)
+    qn2p = pad_to(qn2.reshape(-1, 1), (tile_m, 1), 0)
+    taup = pad_to(tau.reshape(-1, 1), (tile_m, 1), jnp.float32(-jnp.inf))
+    accp = pad_to(acc, (tile_m, tile_n), jnp.float32(jnp.inf))
     s2p = jnp.asarray(scale2, jnp.float32).reshape(1, 1)
-    mp, np_ = accp.shape
-    dp = xp.shape[1]
-    nm, nn, nk = mp // tile_m, np_ // tile_n, dp // tile_k
+    skip = tile_skip_map(accp, tile_m, tile_n)
 
-    out, skip = pl.pallas_call(
-        functools.partial(_kernel, nk=nk, prune=prune),
-        grid=(nm, nn, nk),
-        in_specs=[
-            pl.BlockSpec((tile_n, tile_k), lambda i, j, k: (j, k)),   # x codes
-            pl.BlockSpec((1, tile_n), lambda i, j, k: (0, j)),        # xn2
-            pl.BlockSpec((tile_m, tile_k), lambda i, j, k: (i, k)),   # q codes
-            pl.BlockSpec((tile_m, 1), lambda i, j, k: (i, 0)),        # qn2
-            pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),             # s²
-            pl.BlockSpec((tile_m, tile_n), lambda i, j, k: (i, j)),   # acc
-            pl.BlockSpec((tile_m, 1), lambda i, j, k: (i, 0)),        # tau
+    out = tiled_distance_call(
+        functools.partial(_kernel, prune=prune),
+        (xp, xn2p, qp, qn2p, s2p, accp, taup),
+        [
+            pl.BlockSpec((tile_n, tile_k), lambda i, j, k, live: (j, k)),   # x codes
+            pl.BlockSpec((1, tile_n), lambda i, j, k, live: (0, j)),        # xn2
+            pl.BlockSpec((tile_m, tile_k), lambda i, j, k, live: (i, k)),   # q codes
+            pl.BlockSpec((tile_m, 1), lambda i, j, k, live: (i, 0)),        # qn2
+            pl.BlockSpec(memory_space=pltpu.SMEM),                          # s²
+            pl.BlockSpec((tile_m, tile_n), lambda i, j, k, live: (i, j)),   # acc
+            pl.BlockSpec((tile_m, 1), lambda i, j, k, live: (i, 0)),        # tau
         ],
-        out_specs=[
-            pl.BlockSpec((tile_m, tile_n), lambda i, j, k: (i, j)),   # out
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),             # skip map
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-            jax.ShapeDtypeStruct((nm, nn), jnp.int32),
-        ],
-        interpret=interpret,
-    )(xp, xn2p, qp, qn2p, s2p, accp, taup)
+        skip=skip, tile_m=tile_m, tile_n=tile_n, tile_k=tile_k,
+        dp=xp.shape[1], interpret=interpret,
+    )
     return out[:m, :n], skip

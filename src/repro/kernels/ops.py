@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.distance import partial_distance_update as _pallas_update
+from repro.kernels.distance import tile_skip_map
 from repro.kernels.distance_int8 import (
     int8_partial_distance_update as _pallas_update_int8,
 )
@@ -59,7 +60,7 @@ def partial_distance_update(
     out = ref.partial_distance_update_ref(
         x, xn2, q, qn2, acc, tau, prune=prune, metric=metric
     )
-    skip = _tile_skip_map(acc, tile_m, tile_n)
+    skip = tile_skip_map(acc, tile_m, tile_n)
     return out, skip
 
 
@@ -98,18 +99,8 @@ def int8_partial_distance_update(
     out = ref.int8_partial_distance_update_ref(
         x, xn2, q, qn2, scale2, acc, tau, prune=prune
     )
-    skip = _tile_skip_map(acc, tile_m, tile_n)
+    skip = tile_skip_map(acc, tile_m, tile_n)
     return out, skip
-
-
-def _tile_skip_map(acc: jnp.ndarray, tile_m: int, tile_n: int) -> jnp.ndarray:
-    """Which [tile_m, tile_n] tiles were fully pruned on entry (post-hoc)."""
-    m, n = acc.shape
-    mp, np_ = -(-m // tile_m) * tile_m, -(-n // tile_n) * tile_n
-    a = jnp.pad(acc, ((0, mp - m), (0, np_ - n)), constant_values=jnp.inf)
-    a = a.reshape(mp // tile_m, tile_m, np_ // tile_n, tile_n)
-    alive = jnp.isfinite(a).any(axis=(1, 3))
-    return (~alive).astype(jnp.int32)
 
 
 def running_topk_update(

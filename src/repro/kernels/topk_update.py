@@ -26,47 +26,52 @@ from jax.experimental import pallas as pl
 def _kernel(scores_ref, ids_ref, run_s_ref, run_i_ref, out_s_ref, out_i_ref,
             *, k: int):
     """scores [bm, C] f32 (+inf = invalid), ids [bm, C] i32,
-    run_s/run_i [bm, K] (ascending). Outputs the merged top-K."""
+    run_s/run_i [bm, K] (ascending). Outputs the merged top-K.
+
+    Every per-row pick is a compare-and-select against a lane iota
+    followed by a lane reduction — no gathers, which Mosaic cannot
+    lower — and every intermediate stays 2-D ([bm, 1] columns)."""
     cand_s = scores_ref[...]
     cand_i = ids_ref[...]
     run_s = run_s_ref[...]
     run_i = run_i_ref[...]
+    bm, c = cand_s.shape
+    cand_col = jax.lax.broadcasted_iota(jnp.int32, (bm, c), 1)
+    run_col = jax.lax.broadcasted_iota(jnp.int32, (bm, k), 1)
+
+    def pick(mask, vals, fill):
+        # the single selected entry of each row (``fill`` where none is)
+        if jnp.issubdtype(vals.dtype, jnp.floating):
+            return jnp.min(jnp.where(mask, vals, fill), axis=1, keepdims=True)
+        return jnp.max(jnp.where(mask, vals, fill), axis=1, keepdims=True)
 
     # K passes: extract the global min among (remaining run slot, remaining
     # candidates). run is sorted ascending, so its "cursor" is an index.
-    bm = cand_s.shape[0]
-    rows = jnp.arange(bm)
-
-    def body(state, _):
-        out_s, out_i, slot, cursor, cand_s, run_taken = state
+    out_s = jnp.full((bm, k), jnp.inf, jnp.float32)
+    out_i = jnp.full((bm, k), -1, jnp.int32)
+    cursor = jnp.zeros((bm, 1), jnp.int32)
+    int_min = jnp.iinfo(jnp.int32).min
+    for slot in range(k):                   # static K unroll
         # current head of the running list per row
-        head_s = jnp.take_along_axis(run_s, cursor[:, None], axis=1)[:, 0]
-        head_i = jnp.take_along_axis(run_i, cursor[:, None], axis=1)[:, 0]
-        # best remaining candidate per row
-        cmin = jnp.min(cand_s, axis=1)
-        carg = jnp.argmin(cand_s, axis=1).astype(jnp.int32)
-        cid = jnp.take_along_axis(cand_i, carg[:, None], axis=1)[:, 0]
+        at_head = run_col == cursor
+        head_s = pick(at_head, run_s, jnp.inf)
+        head_i = pick(at_head, run_i, int_min)
+        # best remaining candidate per row (first index among equal minima)
+        cmin = jnp.min(cand_s, axis=1, keepdims=True)
+        carg = jnp.min(jnp.where(cand_s == cmin, cand_col, c), axis=1,
+                       keepdims=True)
+        at_carg = cand_col == carg
+        cid = pick(at_carg, cand_i, int_min)
         take_run = head_s <= cmin
         sel_s = jnp.where(take_run, head_s, cmin)
         sel_i = jnp.where(take_run, head_i, cid)
-        out_s = out_s.at[:, slot].set(sel_s)
-        out_i = out_i.at[:, slot].set(sel_i)
+        out_s = jnp.where(run_col == slot, sel_s, out_s)
+        out_i = jnp.where(run_col == slot, sel_i, out_i)
         cursor = jnp.where(take_run, cursor + 1, cursor)
         # knock out the taken candidate
-        knock = (~take_run)[:, None] & (
-            jnp.arange(cand_s.shape[1])[None, :] == carg[:, None]
-        )
-        cand_s = jnp.where(knock, jnp.inf, cand_s)
-        return (out_s, out_i, slot + 1, cursor, cand_s, run_taken), None
-
-    out_s0 = jnp.full(run_s.shape, jnp.inf, jnp.float32)
-    out_i0 = jnp.full(run_i.shape, -1, jnp.int32)
-    cursor0 = jnp.zeros((bm,), jnp.int32)
-    state = (out_s0, out_i0, 0, cursor0, cand_s, None)
-    for _ in range(k):                      # static K unroll
-        state, _ = body(state, None)
-    out_s_ref[...] = state[0]
-    out_i_ref[...] = state[1]
+        cand_s = jnp.where(at_carg & ~take_run, jnp.inf, cand_s)
+    out_s_ref[...] = out_s
+    out_i_ref[...] = out_i
 
 
 @functools.partial(

@@ -715,6 +715,12 @@ class HarmonyServer(DataPlane):
                     else (snap.generation, snap.dead_version),
                 )
             seg_results.append(res)
+        # a batch counts as device-served only if every sealed segment ran
+        # on its executor (int64 ids and precision overrides take the host
+        # engine even under backend="spmd")
+        on_device = backend == "spmd" and all(
+            r.stats.get("backend") == "spmd" for r in seg_results
+        )
         parts = [(r.scores, r.ids) for r in seg_results]
         delta_live = snap.delta_live
         if flt is not None and snap.delta_ids.size:
@@ -737,11 +743,11 @@ class HarmonyServer(DataPlane):
             else:
                 scores, ids = merge_topk(parts, k, fused=(backend == "spmd"))
             res = SearchResult(ids=ids, scores=scores, stats={
-                "backend": backend,
                 "segments": len(seg_results),
                 "delta_candidates": int(delta_live.sum()),
                 "generation": snap.generation,
             })
+        res.stats["backend"] = "spmd" if on_device else "host"
         if hybrid_text is not None:
             lex = self._lexical_topk(
                 snap, states, hybrid_text, k, flt, delta_live
@@ -766,7 +772,7 @@ class HarmonyServer(DataPlane):
             self.stats.prefetch_hits += res.stats["prefetch_hits"]
         dt = time.perf_counter() - t0
         res.stats["wall_s"] = dt
-        if backend == "spmd":
+        if on_device:
             self.stats.spmd_batches += 1
         self.stats.batches += 1
         self.stats.queries += queries.shape[0]

@@ -268,25 +268,29 @@ class SpmdExecutor:
         for w in widths:
             for qb in self.qb_buckets:
                 for cap in self.cap_buckets:
-                    bscfg = dataclasses.replace(
-                        self._base_scfg, qb=qb, cap=cap, k=k_step, nprobe=w
-                    )
-                    step = self._get_step(bscfg)
-                    rows = np.full((bscfg.v_shards, cap), -1, np.int32)
-                    rows[:, 0] = 0
-                    qarr = build_query_arrays(
-                        np.zeros((1, self.index.dim), np.float32), bscfg,
-                        np.zeros((1, w), np.int32),
-                        np.full((1,), np.inf, np.float32),
-                        quant_grid=self._quant_grid,
-                    )
-                    if self.tier == "host":
-                        cand, _ = self._upload_candidates(rows, cap)
-                        step(*cand,
-                             qarr["queries"], qarr["probes"], qarr["tau0"])
-                    else:
-                        step(*self._resident, rows,
-                             qarr["queries"], qarr["probes"], qarr["tau0"])
+                    key = (qb, cap, k_step, w)
+                    self._get_step(self._bucket_cfg(*key))(
+                        *self._warmup_args(*key))
+
+    def _bucket_cfg(self, qb: int, cap: int, k: int, nprobe: int) -> SpmdConfig:
+        return dataclasses.replace(self._base_scfg, qb=qb, cap=cap, k=k,
+                                   nprobe=nprobe)
+
+    def _warmup_args(self, qb: int, cap: int, k: int, nprobe: int) -> tuple:
+        """Inputs of the (qb, cap, k, nprobe) bucket step for one dummy
+        query over one candidate row."""
+        bscfg = self._bucket_cfg(qb, cap, k, nprobe)
+        rows = np.full((bscfg.v_shards, cap), -1, np.int32)
+        rows[:, 0] = 0
+        qarr = build_query_arrays(
+            np.zeros((1, self.index.dim), np.float32), bscfg,
+            np.zeros((1, nprobe), np.int32),
+            np.full((1,), np.inf, np.float32),
+            quant_grid=self._quant_grid,
+        )
+        cand = (self._upload_candidates(rows, cap)[0] if self.tier == "host"
+                else (*self._resident, rows))
+        return (*cand, qarr["queries"], qarr["probes"], qarr["tau0"])
 
     # ----------------------------------------------------------- bucketing
     def _pick_bucket(self, ladder: Tuple[int, ...], need: int) -> int:
@@ -594,9 +598,7 @@ class SpmdExecutor:
                 probes = np.concatenate([probes.astype(np.int32), pad], axis=1)
         k_step = min(k * self.cfg.rerank_factor, self.index.nb) if int8 else k
         qb_b = self._pick_bucket(self.qb_buckets, nq)
-        bscfg = dataclasses.replace(
-            self._base_scfg, qb=qb_b, cap=cap_b, k=k_step, nprobe=probes.shape[1]
-        )
+        bscfg = self._bucket_cfg(qb_b, cap_b, k_step, probes.shape[1])
         qarr = build_query_arrays(queries, bscfg, probes, tau0,
                                   quant_grid=self._quant_grid)
         compiles_before = self.compiles

@@ -158,6 +158,19 @@ def test_scheduled_spmd_backend_matches_oracle(anns):
     )
 
 
+def test_host_fallback_is_not_counted_as_spmd(anns):
+    """A precision override the executor was not built for is served by
+    the host engine even under backend="spmd": that batch must not count
+    as device-served."""
+    ds, cfg, index, q = anns
+    srv = HarmonyServer(index, n_nodes=4, backend="spmd",
+                        executor_cfg=ExecutorConfig(chunk=128, qb_buckets=(16,)))
+    dev = srv.search_batch(q[:8], k=5)
+    host = srv.search_batch(q[:8], k=5, precision="int8")
+    assert (dev.stats["backend"], host.stats["backend"]) == ("spmd", "host")
+    assert (srv.stats.batches, srv.stats.spmd_batches) == (2, 1)
+
+
 def test_serve_arrival_stream_drives_batch_formation(anns):
     """Per-batch arrival timestamps must reach the scheduler: far-apart
     arrivals form one deadline batch each instead of one merged batch, and
