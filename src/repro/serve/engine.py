@@ -59,6 +59,7 @@ from repro.core.fusion import BM25Index, reciprocal_rank_fusion, segment_bm25
 from repro.core.index import meta_rows_to_store
 from repro.core.types import DataPlane, Filter, SearchRequest, SearchResult
 from repro.runtime import ClusterState
+from repro.serve import spans
 
 
 @dataclass
@@ -648,6 +649,11 @@ class HarmonyServer(DataPlane):
         negated RRF, ``stats["fused"]=True``). ``precision`` overrides
         the server's tier per batch; an override that differs from the
         executor's compiled precision is served by the host engine."""
+        with spans.span("engine"):
+            return self._search_batch(queries, k, backend, flt, hybrid_text,
+                                      precision)
+
+    def _search_batch(self, queries, k, backend, flt, hybrid_text, precision):
         if isinstance(queries, SearchRequest):
             req = queries
             queries = np.atleast_2d(np.asarray(req.vector, np.float32))
@@ -682,17 +688,19 @@ class HarmonyServer(DataPlane):
             seg = st.segment
             dead = snap.dead_rows[seg.seg_id]
             dead_arg = filter_excluded_rows(seg.index, flt, dead)
-            if flt is None:
-                probes = assign_queries(seg.index, queries)
-            else:
-                # predicate pushdown: clusters with no allowed live row
-                # drop out of probe selection entirely
-                probes = filtered_assign_queries(seg.index, queries, dead_arg)
-            # feed the placement policy's cluster-hotness EWMA with the
-            # actual probe selection (every segment, every batch)
-            self.data.note_probes(seg.seg_id, probes)
-            if seg is primary:
-                self._recent_probes.append(probes)
+            with spans.span("engine.probe"):
+                if flt is None:
+                    probes = assign_queries(seg.index, queries)
+                else:
+                    # predicate pushdown: clusters with no allowed live
+                    # row drop out of probe selection entirely
+                    probes = filtered_assign_queries(seg.index, queries,
+                                                     dead_arg)
+                # feed the placement policy's cluster-hotness EWMA with
+                # the actual probe selection (every segment, every batch)
+                self.data.note_probes(seg.seg_id, probes)
+                if seg is primary:
+                    self._recent_probes.append(probes)
             if backend == "spmd" and st.int32_ids and prec == self.precision:
                 res = self._executor_for(st).search_batch(
                     queries, k=k, probes=probes, dead_rows=dead_arg

@@ -70,6 +70,7 @@ from repro.core.pipeline import (
 from repro.core.pruning import prewarm_tau
 from repro.core.router import load_aware_assignment, ring_offsets
 from repro.core.types import PartitionPlan, SearchResult
+from repro.serve import spans
 
 
 @dataclass(frozen=True)
@@ -235,6 +236,10 @@ class SpmdExecutor:
         self.wall_s = 0.0
         self.tile_skipped = 0
         self.tile_total = 0
+        # live rows of the gather tables, and the padded rows the step
+        # scans (V × cap bucket): their ratio is the cap ladder's fill
+        self.rows_gathered = 0
+        self.rows_scanned = 0
         # cold-tier counters (always 0 for a device-tier executor)
         self.cold_dispatches = 0
         self.bytes_streamed = 0
@@ -302,8 +307,8 @@ class SpmdExecutor:
     def _gather_rows(self, probes: np.ndarray,
                      dead_rows: Optional[np.ndarray] = None):
         """Per-shard union of probed clusters' resident row ranges, padded
-        to the smallest cap bucket. Returns (rows [V, cap_b] i32, cap_b);
-        (None, 0) when the batch probes no resident rows.
+        to the smallest cap bucket. Returns (rows [V, cap_b] i32, cap_b,
+        live rows); (None, 0, 0) when the batch probes no resident rows.
 
         ``dead_rows`` (bool [NB] over *packed* index rows — the mutable
         data plane's tombstones) drops dead rows from the gather table, so
@@ -327,14 +332,14 @@ class SpmdExecutor:
                     counts[v] += r.size
         need = int(counts.max()) if len(uniq) else 0
         if need == 0:
-            return None, 0
+            return None, 0, 0
         cap_b = self._pick_bucket(self.cap_buckets, need)
         rows = np.full((V, cap_b), -1, np.int32)
         for v in range(V):
             if per_shard[v]:
                 r = np.concatenate(per_shard[v])
                 rows[v, : len(r)] = r
-        return rows, cap_b
+        return rows, cap_b, int(counts.sum())
 
     # --------------------------------------------------------- compilation
     def _get_step(self, bscfg: SpmdConfig):
@@ -484,7 +489,7 @@ class SpmdExecutor:
             probes = assign_queries(self.index, queries, nprobe)
         max_qb = self.qb_buckets[-1]
         for lo in range(0, probes.shape[0], max_qb):
-            rows, cap_b = self._gather_rows(probes[lo:lo + max_qb], dead_rows)
+            rows, cap_b, _ = self._gather_rows(probes[lo:lo + max_qb], dead_rows)
             if cap_b == 0:
                 continue
             key = (rows.tobytes(), cap_b)
@@ -547,6 +552,15 @@ class SpmdExecutor:
                 },
             )
 
+        with spans.span("executor") as span:
+            return self._search_bucket(queries, k, nprobe, probes, dead_rows,
+                                       span)
+
+    def _search_bucket(self, queries, k, nprobe, probes, dead_rows,
+                       span) -> SearchResult:
+        """One dispatch of at most the largest qb bucket, inside the
+        ``executor`` span; ``span`` gets the bucket and the live rows."""
+        nq = queries.shape[0]
         t0 = time.perf_counter()
         if probes is None:
             if nprobe is not None and nprobe <= 0:
@@ -555,7 +569,8 @@ class SpmdExecutor:
                 probes = np.zeros((nq, 0), np.int32)
             else:
                 probes = assign_queries(self.index, queries, nprobe)
-        rows, cap_b = self._gather_rows(probes, dead_rows)
+        with spans.span("executor.gather_table"):
+            rows, cap_b, live = self._gather_rows(probes, dead_rows)
         if cap_b == 0:
             dt = time.perf_counter() - t0
             self.dispatches += 1
@@ -573,69 +588,76 @@ class SpmdExecutor:
                     "bytes_streamed": 0, "prefetch_hits": 0,
                 },
             )
+        qb_b = self._pick_bucket(self.qb_buckets, nq)
+        span.set_metadata(qb=qb_b, cap=cap_b, rows=live)
         int8 = self.precision == "int8"
         # τ prewarm runs over the *original* probe table: prewarm_tau
         # indexes per-cluster sample rows, so pad columns (-2) must never
         # reach it. int8 stage 1 scores in the quantized metric, where an
         # fp32-space τ seed is not a valid upper bound — start at +inf and
         # let the travelling τ tighten within the quantized metric instead.
-        tau0 = (
-            prewarm_tau(self.index, queries, probes, k,
-                        self.index.cfg.prewarm_samples, self.metric,
-                        dead_rows=dead_rows)
-            if self.prune and not int8
-            else np.full((nq,), np.inf, np.float32)
-        )
-        # compile-cache alignment: the step keys on probes.shape[1]; pad a
-        # narrower probe table (-2 columns match no cluster) up to the
-        # smallest already-compiled width so explicit-probe dispatches hit
-        # warmed steps instead of recompiling per width.
-        w = probes.shape[1]
-        if w not in self._probe_widths:
-            wider = sorted(pw for pw in self._probe_widths if pw > w)
-            if wider:
-                pad = np.full((nq, wider[0] - w), -2, np.int32)
-                probes = np.concatenate([probes.astype(np.int32), pad], axis=1)
-        k_step = min(k * self.cfg.rerank_factor, self.index.nb) if int8 else k
-        qb_b = self._pick_bucket(self.qb_buckets, nq)
-        bscfg = self._bucket_cfg(qb_b, cap_b, k_step, probes.shape[1])
-        qarr = build_query_arrays(queries, bscfg, probes, tau0,
-                                  quant_grid=self._quant_grid)
-        compiles_before = self.compiles
-        step = self._get_step(bscfg)
-        cold_bytes, pf_hit = 0, 0
-        if self.tier == "host":
-            pkey = (rows.tobytes(), cap_b)
-            staged = self._prefetched.pop(pkey, None)
-            if staged is not None:
-                cand, cold_bytes = staged
-                pf_hit = 1
-                self.prefetch_hits += 1
-            else:
-                cand, cold_bytes = self._upload_candidates(rows, cap_b)
-                self.prefetch_misses += 1
-            self.cold_dispatches += 1
-            self.bytes_streamed += cold_bytes
-            gs, gi, st = step(
-                *cand, qarr["queries"], qarr["probes"], qarr["tau0"],
-            )
+        if self.prune and not int8:
+            with spans.span("executor.prewarm"):
+                tau0 = prewarm_tau(self.index, queries, probes, k,
+                                   self.index.cfg.prewarm_samples,
+                                   self.metric, dead_rows=dead_rows)
         else:
-            gs, gi, st = step(
-                *self._resident, rows,
-                qarr["queries"], qarr["probes"], qarr["tau0"],
-            )
-        scores = np.asarray(gs)[:nq]
-        ids = np.asarray(gi)[:nq].astype(np.int64)
+            tau0 = np.full((nq,), np.inf, np.float32)
+        k_step = min(k * self.cfg.rerank_factor, self.index.nb) if int8 else k
+        compiles_before = self.compiles
+        cold_bytes, pf_hit = 0, 0
+        with spans.span("executor.launch"):
+            # compile-cache alignment: the step keys on probes.shape[1];
+            # pad a narrower probe table (-2 columns match no cluster) up
+            # to the smallest already-compiled width so explicit-probe
+            # dispatches hit warmed steps instead of recompiling per width.
+            w = probes.shape[1]
+            if w not in self._probe_widths:
+                wider = sorted(pw for pw in self._probe_widths if pw > w)
+                if wider:
+                    pad = np.full((nq, wider[0] - w), -2, np.int32)
+                    probes = np.concatenate([probes.astype(np.int32), pad],
+                                            axis=1)
+            bscfg = self._bucket_cfg(qb_b, cap_b, k_step, probes.shape[1])
+            qarr = build_query_arrays(queries, bscfg, probes, tau0,
+                                      quant_grid=self._quant_grid)
+            step = self._get_step(bscfg)
+            if self.tier == "host":
+                pkey = (rows.tobytes(), cap_b)
+                staged = self._prefetched.pop(pkey, None)
+                if staged is not None:
+                    cand, cold_bytes = staged
+                    pf_hit = 1
+                    self.prefetch_hits += 1
+                else:
+                    cand, cold_bytes = self._upload_candidates(rows, cap_b)
+                    self.prefetch_misses += 1
+                self.cold_dispatches += 1
+                self.bytes_streamed += cold_bytes
+                gs, gi, st = step(
+                    *cand, qarr["queries"], qarr["probes"], qarr["tau0"],
+                )
+            else:
+                gs, gi, st = step(
+                    *self._resident, rows,
+                    qarr["queries"], qarr["probes"], qarr["tau0"],
+                )
+        with spans.span("executor.wait"):
+            scores = np.asarray(gs)[:nq]
+            ids = np.asarray(gi)[:nq].astype(np.int64)
+            st = np.asarray(st)
         ids[~np.isfinite(scores)] = -1
         if int8:
-            scores, ids = self._rerank(queries, scores, ids, k)
-        st = np.asarray(st)
+            with spans.span("executor.rerank"):
+                scores, ids = self._rerank(queries, scores, ids, k)
         dt = time.perf_counter() - t0
         self.dispatches += 1
         self.queries += nq
         self.wall_s += dt
         self.tile_skipped += int(st[0])
         self.tile_total += int(st[1])
+        self.rows_gathered += live
+        self.rows_scanned += rows.size
         return SearchResult(
             ids=ids,
             scores=scores,
@@ -725,4 +747,6 @@ class SpmdExecutor:
             "tile_skipped": self.tile_skipped,
             "tile_total": self.tile_total,
             "tile_skip_frac": self.tile_skipped / max(self.tile_total, 1),
+            "rows_gathered": self.rows_gathered,
+            "rows_scanned": self.rows_scanned,
         }

@@ -63,6 +63,7 @@ import warnings
 from repro.core.types import DataPlane, SearchRequest
 from repro.serve.cache import QueryCache, build_query_cache
 from repro.serve.clock import Clock, MonotonicClock
+from repro.serve import spans
 from repro.serve.scheduler import (
     DispatchTarget,
     Request,
@@ -318,13 +319,15 @@ class ServingFrontend(DataPlane):
                 if self._inflight >= self.max_inflight:
                     self._mu.wait(timeout=0.05)
                     continue
-                batch = [
-                    self.queue.popleft()
-                    for _ in range(min(len(self.queue), self.max_batch))
-                ]
+                n = min(len(self.queue), self.max_batch)
+                bid = self._batch_id
+                # popping the batch → the pool taking it; ended below
+                dispatch_span = spans.span("frontend.dispatch", batch=bid,
+                                           queued=len(self.queue) - n)
+                dispatch_span.__enter__()
+                batch = [self.queue.popleft() for _ in range(n)]
                 futs = [self._futures.pop(r.req_id) for r in batch]
                 self._inflight += 1
-                bid = self._batch_id
                 self._batch_id += 1
                 dispatch_s = now
             try:
@@ -341,6 +344,8 @@ class ServingFrontend(DataPlane):
                 for fl in fols:
                     for _, f in fl:
                         f.cancel()
+            finally:
+                dispatch_span.__exit__(None, None, None)
 
     def _detach_followers(self, batch) -> List[list]:
         """Pop each batch request's coalesced followers and release its
@@ -368,6 +373,11 @@ class ServingFrontend(DataPlane):
 
     def _run_batch(self, batch, futs, dispatch_s: float, trigger: str,
                    bid: int):
+        with spans.span("frontend.batch", batch=bid, size=len(batch)):
+            self._serve_batch(batch, futs, dispatch_s, trigger, bid)
+
+    def _serve_batch(self, batch, futs, dispatch_s: float, trigger: str,
+                     bid: int):
         # per-request deadline enforcement at dispatch: a request whose
         # absolute deadline passed while it queued degrades to the
         # sentinel shape (PR 7), never executes. Its coalesced followers
@@ -522,37 +532,39 @@ class ServingFrontend(DataPlane):
                 self.last_done_s = max(self.last_done_s, done_s)
             self._mu.notify_all()
         # complete futures outside the lock: done-callbacks run inline
-        if err is not None:
-            for fut in futs:
-                fut.set_exception(err)
-            for fl in fols:
-                for _, ffut in fl:
-                    ffut.set_exception(err)
-        else:
-            for row, (req, fut) in enumerate(zip(batch, futs)):
-                fut.set_result(
-                    RequestResult(
-                        req_id=req.req_id,
-                        ids=row_ids[row],
-                        scores=row_scores[row],
-                        arrival_s=req.arrival_s,
-                        dispatch_s=dispatch_s,
-                        done_s=done_s,
-                        batch_id=bid,
-                    )
-                )
-                for freq, ffut in fols[row]:
-                    ffut.set_result(
+        with spans.span("frontend.complete"):
+            if err is not None:
+                for fut in futs:
+                    fut.set_exception(err)
+                for fl in fols:
+                    for _, ffut in fl:
+                        ffut.set_exception(err)
+            else:
+                for row, (req, fut) in enumerate(zip(batch, futs)):
+                    fut.set_result(
                         RequestResult(
-                            req_id=freq.req_id,
+                            req_id=req.req_id,
                             ids=row_ids[row],
                             scores=row_scores[row],
-                            arrival_s=freq.arrival_s,
+                            arrival_s=req.arrival_s,
                             dispatch_s=dispatch_s,
                             done_s=done_s,
                             batch_id=bid,
                         )
                     )
+                    for freq, ffut in fols[row]:
+                        ffut.set_result(
+                            RequestResult(
+                                req_id=freq.req_id,
+                                ids=row_ids[row],
+                                scores=row_scores[row],
+                                arrival_s=freq.arrival_s,
+                                dispatch_s=dispatch_s,
+                                done_s=done_s,
+                                batch_id=bid,
+                            )
+                        )
+        if err is None:
             try:
                 with self._skew_mu:         # serialized hot-mass check
                     self._skew.after_batch()
