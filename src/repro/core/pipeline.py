@@ -398,7 +398,10 @@ def ring_chunk_search(scfg: SpmdConfig, x_blk, xn2_blk, cluster_ids, row_ids,
     Runs the chunked dimension-ring scan (Pallas partial-distance with
     tile-granular early-stop, ppermute rotation, running top-K with τ
     tightening between chunks) and merges results across the mesh axes.
-    Returns replicated (scores [qb, K], ids [qb, K], stats [2]).
+    Returns replicated (scores [qb, K], ids [qb, K], stats [4]): the
+    distance tiles skipped and scored, the top-K insertion passes run
+    (``kops.topk_pass_counts``, summed) and their most (chunks × query
+    tiles × K), each summed over the mesh.
 
     ``precision="int8"``: x_blk/q_blk carry int8 codes, xn2_blk the
     pre-scaled s²·Σcode² norms, and ``scale2`` this device's scalar s².
@@ -425,7 +428,7 @@ def ring_chunk_search(scfg: SpmdConfig, x_blk, xn2_blk, cluster_ids, row_ids,
     perm = [(i, (i + 1) % B) for i in range(B)]
 
     def outer(carry, c):
-        run_scores, run_ids, skip_cnt, tile_cnt = carry
+        run_scores, run_ids, skip_cnt, tile_cnt, pass_cnt, slot_cnt = carry
         row0 = c * chunk
         x_c = jax.lax.dynamic_slice_in_dim(x_blk, row0, chunk, 0)
         xn2_c = jax.lax.dynamic_slice_in_dim(xn2_blk, row0, chunk, 0)
@@ -465,11 +468,15 @@ def ring_chunk_search(scfg: SpmdConfig, x_blk, xn2_blk, cluster_ids, row_ids,
         )
         # after B stages (and B ppermutes) the accumulator is home again;
         # merge the chunk into the running top-K (fused VMEM-resident kernel
-        # on the Pallas path, concat+sort on the jnp path)
+        # on the Pallas path, concat+sort on the jnp path). Both paths count
+        # the kernel's insertion passes, so the counter reads the same.
         id_b = jnp.broadcast_to(id_c[None, :], acc.shape)
+        passes = kops.topk_pass_counts(acc, run_scores, k=K)
+        pass_cnt = pass_cnt + passes.sum()
+        slot_cnt = slot_cnt + passes.size * K
         if scfg.use_pallas:
             run_scores, run_ids = kops.running_topk_update(
-                acc, id_b, run_scores, run_ids, k=K
+                acc, id_b, run_scores, run_ids, passes, k=K
             )
         else:
             cat_s = jnp.concatenate([run_scores, acc], axis=1)
@@ -477,12 +484,16 @@ def ring_chunk_search(scfg: SpmdConfig, x_blk, xn2_blk, cluster_ids, row_ids,
             neg, pos = jax.lax.top_k(-cat_s, K)
             run_scores = -neg
             run_ids = jnp.take_along_axis(cat_i, pos, axis=1)
-        return (run_scores, run_ids, skip_cnt, tile_cnt), None
+        return (run_scores, run_ids, skip_cnt, tile_cnt, pass_cnt,
+                slot_cnt), None
 
-    (run_scores, run_ids, skip_cnt, tile_cnt), _ = jax.lax.scan(
-        outer,
-        (run_scores0, run_ids0, jnp.int32(0), jnp.int32(0)),
-        jnp.arange(n_chunks),
+    zero = jnp.int32(0)
+    (run_scores, run_ids, skip_cnt, tile_cnt, pass_cnt, slot_cnt), _ = (
+        jax.lax.scan(
+            outer,
+            (run_scores0, run_ids0, zero, zero, zero, zero),
+            jnp.arange(n_chunks),
+        )
     )
 
     # ---- gather groups across the model axis and restore group order
@@ -512,11 +523,8 @@ def ring_chunk_search(scfg: SpmdConfig, x_blk, xn2_blk, cluster_ids, row_ids,
         gs = -neg
         gi = jnp.take_along_axis(pi, pos, axis=1)
 
-    stats = jnp.stack(
-        [
-            jax.lax.psum(skip_cnt, scfg.axis_model),
-            jax.lax.psum(tile_cnt, scfg.axis_model),
-        ]
+    stats = jax.lax.psum(
+        jnp.stack([skip_cnt, tile_cnt, pass_cnt, slot_cnt]), scfg.axis_model
     )
     stats = jax.lax.psum(stats, scfg.axis_data)
     if scfg.n_pods > 1:

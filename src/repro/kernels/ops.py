@@ -19,7 +19,9 @@ from repro.kernels.distance import tile_skip_map
 from repro.kernels.distance_int8 import (
     int8_partial_distance_update as _pallas_update_int8,
 )
+from repro.kernels.topk_update import TILE_M as TOPK_TILE_M
 from repro.kernels.topk_update import running_topk_update as _pallas_topk
+from repro.kernels.topk_update import topk_pass_counts
 
 
 def _on_tpu() -> bool:
@@ -108,9 +110,10 @@ def running_topk_update(
     ids: jnp.ndarray,         # [M, C] i32
     run_s: jnp.ndarray,       # [M, K] f32 ascending
     run_i: jnp.ndarray,       # [M, K] i32
+    passes: jnp.ndarray | None = None,
     *,
     k: int,
-    tile_m: int = 8,
+    tile_m: int = TOPK_TILE_M,
     use_pallas: bool = True,
     interpret: bool | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -118,12 +121,15 @@ def running_topk_update(
 
     Routes to the fused VMEM-resident Pallas kernel (interpret-mode off
     TPU) or the concat+sort jnp oracle with ``use_pallas=False``.
+    ``passes`` (the kernel's per-tile pass counts, ``topk_pass_counts``)
+    is computed by the kernel's wrapper when not given.
     """
     if interpret is None:
         interpret = not _on_tpu()
     if use_pallas:
         return _pallas_topk(
-            scores, ids, run_s, run_i, k=k, tile_m=tile_m, interpret=interpret
+            scores, ids, run_s, run_i, passes, k=k, tile_m=tile_m,
+            interpret=interpret,
         )
     return ref.running_topk_ref(scores, ids, run_s, run_i, k=k)
 

@@ -95,6 +95,12 @@ class ExecutorConfig:
     prune: Optional[bool] = None    # None → index.cfg.enable_pruning (L2 only)
 
 
+def _pass_stats(passes: int, slots: int) -> dict:
+    """The top-K merge's pass counters and the share of slots it ran."""
+    return {"topk_passes": passes, "topk_pass_slots": slots,
+            "topk_pass_frac": passes / max(slots, 1)}
+
+
 def _default_mesh(d_blocks: int) -> Mesh:
     devs = jax.devices()
     n = len(devs)
@@ -236,6 +242,10 @@ class SpmdExecutor:
         self.wall_s = 0.0
         self.tile_skipped = 0
         self.tile_total = 0
+        # top-K insertion passes run, and the most the chunks could take
+        # (chunks × query tiles × K): their ratio is the merge's work share
+        self.topk_passes = 0
+        self.topk_pass_slots = 0
         # live rows of the gather tables, and the padded rows the step
         # scans (V × cap bucket): their ratio is the cap ladder's fill
         self.rows_gathered = 0
@@ -539,6 +549,9 @@ class SpmdExecutor:
                     "buckets": [b for p in parts for b in p.stats["buckets"]],
                     "tile_skipped": sum(p.stats["tile_skipped"] for p in parts),
                     "tile_total": sum(p.stats["tile_total"] for p in parts),
+                    **_pass_stats(sum(p.stats["topk_passes"] for p in parts),
+                                  sum(p.stats["topk_pass_slots"]
+                                      for p in parts)),
                     "pad_queries": sum(p.stats["pad_queries"] for p in parts),
                     "compiled": any(p.stats["compiled"] for p in parts),
                     "splits": len(parts),
@@ -581,7 +594,8 @@ class SpmdExecutor:
                 scores=np.full((nq, k), np.inf, np.float32),
                 stats={
                     "backend": "spmd", "wall_s": dt, "buckets": [],
-                    "tile_skipped": 0, "tile_total": 0, "pad_queries": 0,
+                    "tile_skipped": 0, "tile_total": 0, **_pass_stats(0, 0),
+                    "pad_queries": 0,
                     "compiled": False, "splits": 1,
                     "precision": self.precision, "rerank_k": 0,
                     "cold": int(self.tier == "host"),
@@ -656,6 +670,8 @@ class SpmdExecutor:
         self.wall_s += dt
         self.tile_skipped += int(st[0])
         self.tile_total += int(st[1])
+        self.topk_passes += int(st[2])
+        self.topk_pass_slots += int(st[3])
         self.rows_gathered += live
         self.rows_scanned += rows.size
         return SearchResult(
@@ -667,6 +683,7 @@ class SpmdExecutor:
                 "buckets": [(qb_b, cap_b)],
                 "tile_skipped": int(st[0]),
                 "tile_total": int(st[1]),
+                **_pass_stats(int(st[2]), int(st[3])),
                 "pad_queries": qb_b - nq,
                 "compiled": self.compiles > compiles_before,
                 "splits": 1,
@@ -747,6 +764,7 @@ class SpmdExecutor:
             "tile_skipped": self.tile_skipped,
             "tile_total": self.tile_total,
             "tile_skip_frac": self.tile_skipped / max(self.tile_total, 1),
+            **_pass_stats(self.topk_passes, self.topk_pass_slots),
             "rows_gathered": self.rows_gathered,
             "rows_scanned": self.rows_scanned,
         }

@@ -73,6 +73,30 @@ def test_parity_pallas_interpret(anns):
     assert res.stats["tile_total"] > 0
 
 
+def test_topk_pass_counter_same_on_pallas_and_jnp_paths(anns):
+    """The step counts the top-K merge's insertion passes the same way on
+    the interpret-mode Pallas path and the jnp path: at most one per slot
+    (chunks × query tiles × K), the same count, the same answers."""
+    ds, cfg, index, q = anns
+    runs = {}
+    for use_pallas in (False, True):
+        ex = _executor(index, use_pallas=use_pallas, tile_m=32, tile_n=64,
+                       tile_k=32)
+        res = ex.search_batch(q[:8])
+        runs[use_pallas] = (res, ex.stats_summary())
+        assert_matches_oracle(res, search_oracle(index, q[:8]))
+        summary = runs[use_pallas][1]
+        assert 0 < summary["topk_passes"] <= summary["topk_pass_slots"]
+        assert summary["topk_pass_frac"] == (summary["topk_passes"]
+                                             / summary["topk_pass_slots"])
+        for key in ("topk_passes", "topk_pass_slots", "topk_pass_frac"):
+            assert res.stats[key] == summary[key]
+    (res_j, sum_j), (res_p, sum_p) = runs[False], runs[True]
+    assert sum_p["topk_passes"] == sum_j["topk_passes"]
+    assert sum_p["topk_pass_slots"] == sum_j["topk_pass_slots"]
+    np.testing.assert_array_equal(res_p.ids, res_j.ids)
+
+
 def test_parity_metric_ip():
     ds = make_dataset(nb=3000, dim=24, n_components=6, spread=0.6, seed=2)
     cfg = HarmonyConfig(dim=24, nlist=24, nprobe=5, topk=5, kmeans_iters=4,
