@@ -33,7 +33,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.config import HarmonyConfig
-from repro.core.kmeans import kmeans_fit_np
+from repro.core.kmeans import kmeans_fit_np, n_blocks
 from repro.core.types import PartitionPlan
 
 
@@ -120,10 +120,15 @@ def build_ivf(
     permuted by the same cluster sort as the vectors, so metadata stays
     row-aligned with the packed corpus.
     """
+    # imported here: the serving package imports this module
+    from repro.serve import spans
+
+    blocks = n_blocks(len(x), cfg.nlist)
     t0 = time.perf_counter()
-    centers, assign = kmeans_fit_np(
-        x, cfg.nlist, iters=cfg.kmeans_iters, seed=cfg.kmeans_seed
-    )
+    with spans.span("index.kmeans", rows=len(x), nlist=cfg.nlist, blocks=blocks):
+        centers, assign = kmeans_fit_np(
+            x, cfg.nlist, iters=cfg.kmeans_iters, seed=cfg.kmeans_seed
+        )
     t_train = time.perf_counter() - t0
 
     t0 = time.perf_counter()
@@ -152,7 +157,7 @@ def build_ivf(
         ids=ids.astype(np.int64),
         cluster_of=cluster_sorted.astype(np.int32),
         offsets=offsets,
-        build_times={"train": t_train, "add": t_add},
+        build_times={"train": t_train, "add": t_add, "kmeans_blocks": blocks},
         meta=store,
     )
 
@@ -179,9 +184,42 @@ def dim_block_bounds(dim: int, d_blocks: int) -> List[Tuple[int, int]]:
     return [(b * per, min(dim, (b + 1) * per)) for b in range(d_blocks)]
 
 
+def padded_block_width(dim: int, d_blocks: int, tile_k: int = 1) -> int:
+    """Columns one dimension block takes on the device: the widest block of
+    :func:`dim_block_bounds`, rounded up to a multiple of the distance
+    kernel's contraction tile ``tile_k``, so the kernel never pads a chunk."""
+    per = -(-dim // d_blocks)
+    return -(-per // tile_k) * tile_k
+
+
+def pack_dim_blocks(out: np.ndarray, x: np.ndarray, d_blocks: int) -> np.ndarray:
+    """Copy ``x`` [..., D] into ``out`` [..., d_blocks * width], zeroed, as
+    ``d_blocks`` blocks of ``width`` columns: block b's columns of
+    :func:`dim_block_bounds` start at ``b * width``, and the columns after
+    them stay zero (they change no norm and no product). Leading axes of
+    ``x`` may be shorter than ``out``'s; they fill its first rows."""
+    width = out.shape[-1] // d_blocks
+    lead = tuple(slice(0, n) for n in x.shape[:-1])
+    for b, (lo, hi) in enumerate(dim_block_bounds(x.shape[-1], d_blocks)):
+        out[lead + (slice(b * width, b * width + hi - lo),)] = x[..., lo:hi]
+    return out
+
+
 # ---------------------------------------------------------------------------
 # Scalar-quantized int8 tier (stage 1 of the two-stage search path)
 # ---------------------------------------------------------------------------
+
+
+def encode_blocks(x: np.ndarray, scale: np.ndarray, zero: np.ndarray) -> np.ndarray:
+    """int8 codes of ``x`` [..., D] on one affine grid per dimension block
+    (``len(scale)`` blocks of :func:`dim_block_bounds`): dimension j of
+    block b encodes as ``round((x_j − zero_b) / scale_b)`` clipped to
+    [−127, 127]."""
+    codes = np.empty(x.shape, np.int8)
+    for b, (lo, hi) in enumerate(dim_block_bounds(x.shape[-1], len(scale))):
+        c = np.rint((x[..., lo:hi] - zero[b]) / scale[b])
+        codes[..., lo:hi] = np.clip(c, -127, 127).astype(np.int8)
+    return codes
 
 
 @dataclass(frozen=True)
@@ -214,12 +252,7 @@ class Int8Quant:
         Out-of-range values (queries may fall outside the corpus's value
         range) clip; the corpus itself never clips because the grid was
         fit to its range."""
-        x = np.asarray(x, np.float32)
-        out = np.empty(x.shape, np.int8)
-        for b, (lo, hi) in enumerate(self.bounds):
-            q = np.rint((x[..., lo:hi] - self.zero[b]) / self.scale[b])
-            out[..., lo:hi] = np.clip(q, -127, 127).astype(np.int8)
-        return out
+        return encode_blocks(np.asarray(x, np.float32), self.scale, self.zero)
 
     def decode(self, codes: Optional[np.ndarray] = None) -> np.ndarray:
         """Dequantize codes [..., D] back to fp32 (default: own corpus)."""
@@ -282,16 +315,13 @@ def quantize_vectors(x: np.ndarray, d_blocks: int) -> Int8Quant:
     bounds = dim_block_bounds(int(x.shape[1]), d_blocks)
     scale = np.ones(d_blocks, np.float32)
     zero = np.zeros(d_blocks, np.float32)
-    codes = np.empty(x.shape, np.int8)
     for b, (lo, hi) in enumerate(bounds):
         blk = x[:, lo:hi]
         mn = float(blk.min()) if blk.size else 0.0
         mx = float(blk.max()) if blk.size else 0.0
         zero[b] = 0.5 * (mn + mx)
         scale[b] = max((mx - mn) / 254.0, 1e-8)
-        q = np.rint((blk - zero[b]) / scale[b])
-        codes[:, lo:hi] = np.clip(q, -127, 127).astype(np.int8)
-    return Int8Quant(codes=codes, scale=scale, zero=zero)
+    return Int8Quant(codes=encode_blocks(x, scale, zero), scale=scale, zero=zero)
 
 
 @dataclass

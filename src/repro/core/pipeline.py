@@ -33,7 +33,13 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.compat import shard_map_compat
-from repro.core.index import IVFIndex, ShardedCorpus, dim_block_bounds
+from repro.core.index import (
+    IVFIndex,
+    ShardedCorpus,
+    dim_block_bounds,
+    encode_blocks,
+    pack_dim_blocks,
+)
 from repro.kernels import ops as kops
 
 
@@ -46,7 +52,7 @@ class SpmdConfig:
     n_pods: int = 1        # pod-axis size (corpus super-shards)
     qb: int = 64           # queries per step (per pod; replicated over pods)
     cap: int = 1024        # padded rows per shard
-    dim: int = 128         # padded to d_blocks * db
+    dim: int = 128         # d_blocks * db; each block padded to db columns
     nprobe: int = 8
     k: int = 10
     chunk: int = 512       # candidate rows scored per ring pass
@@ -108,8 +114,11 @@ def build_corpus_arrays(corpus: ShardedCorpus, scfg: SpmdConfig,
     carries the pre-scaled s²·Σcode² norms, and the grid's (scale, zero)
     come from ``quant`` — the segment's seal-time :class:`Int8Quant` —
     when its blocking matches this mesh, else are fit to this layout.
-    Padded rows *and* padded dims are encoded as literal 0.0 on the same
-    grid queries use, so padding contributes exactly 0 to every distance.
+    Each dimension block is laid out once here at ``scfg.db`` columns
+    (:func:`repro.core.index.pack_dim_blocks`): the block's true columns,
+    then zeros — code 0 for int8, as for queries — so padding contributes
+    exactly 0 to every norm and product, and a ``db`` that is a multiple of
+    the kernel's ``tile_k`` leaves the kernel nothing to pad per chunk.
     """
     V, B = scfg.v_shards, scfg.d_blocks
     cap, D = scfg.cap, scfg.dim
@@ -123,18 +132,13 @@ def build_corpus_arrays(corpus: ShardedCorpus, scfg: SpmdConfig,
     row_ids[:, : xs.shape[1]] = corpus.ids_shard.astype(np.int32)
 
     if scfg.precision == "int8":
-        xf = np.zeros((V, cap, D), np.float32)
-        xf[:, : xs.shape[1], : xs.shape[2]] = xs
-        bounds = dim_block_bounds(D, B)
         scale, zero = _mesh_quant_grid(xs, corpus.valid, scfg, quant)
-        codes = np.empty((V, cap, D), np.int8)
+        true_codes = encode_blocks(xs, scale, zero)
+        codes = pack_dim_blocks(np.zeros((V, cap, D), np.int8), true_codes, B)
         xn2_blocks = np.zeros((B, V, cap), np.float32)
-        for b, (lo, hi) in enumerate(bounds):
-            qb = np.rint((xf[:, :, lo:hi] - zero[b]) / scale[b])
-            cb = np.clip(qb, -127, 127).astype(np.int8)
-            codes[:, :, lo:hi] = cb
-            c32 = cb.astype(np.int32)
-            xn2_blocks[b] = (scale[b] ** 2) * np.sum(c32 * c32, axis=2)
+        for b, (lo, hi) in enumerate(dim_block_bounds(xs.shape[2], B)):
+            c32 = true_codes[:, :, lo:hi].astype(np.int32)
+            xn2_blocks[b, :, : xs.shape[1]] = (scale[b] ** 2) * np.sum(c32 * c32, axis=2)
         return dict(
             x_blocks=codes,
             xn2_blocks=xn2_blocks,
@@ -149,19 +153,18 @@ def build_corpus_arrays(corpus: ShardedCorpus, scfg: SpmdConfig,
     import ml_dtypes
 
     xdt = np.float32 if scfg.x_dtype == "float32" else ml_dtypes.bfloat16
-    x_blocks = np.zeros((V, cap, D), xdt)
-    x_blocks[:, : xs.shape[1], : xs.shape[2]] = xs.astype(xdt)
+    x_blocks = pack_dim_blocks(np.zeros((V, cap, D), xdt), xs.astype(xdt, copy=False), B)
 
     xn2_blocks = np.zeros((B, V, cap), np.float32)
     if xdt is np.float32 and corpus.xnorm2_blk.shape[1] == B:
-        # reuse the per-block norms preassign already materialized (zero
-        # padding — rows or dims — does not change block norms)
+        # reuse the per-block norms preassign already materialized (over
+        # the true columns; zero padding of rows or dims adds nothing)
         xn2_blocks[:, :, : xs.shape[1]] = np.moveaxis(corpus.xnorm2_blk, 0, 1)
     else:
         # dtype cast (or a different block split) changes the norms
-        bounds = dim_block_bounds(D, B)
-        for b, (lo, hi) in enumerate(bounds):
-            seg = x_blocks[:, :, lo:hi]
+        db = scfg.db
+        for b in range(B):
+            seg = x_blocks[:, :, b * db:(b + 1) * db]
             xn2_blocks[b] = np.sum(seg * seg, axis=2)
     return dict(
         x_blocks=x_blocks,
@@ -176,19 +179,19 @@ def _mesh_quant_grid(xs: np.ndarray, valid: np.ndarray, scfg: SpmdConfig,
     """(scale [B], zero [B]) for this mesh's dimension blocking.
 
     Reuses the seal-time grid when its per-block dim ranges coincide with
-    the mesh blocking (the common case: ``quant_blocks == d_blocks`` and
-    minimal dim padding); otherwise fits a fresh grid to the shard
-    layout's valid rows — a deterministic function of the corpus, so
-    every replica derives identical codes."""
-    B, db = scfg.d_blocks, scfg.db
+    the mesh blocking (``quant_blocks == d_blocks``); otherwise fits a
+    fresh grid to the shard layout's valid rows, block by block over the
+    true columns — a deterministic function of the corpus, so every
+    replica derives identical codes."""
+    B, D = scfg.d_blocks, xs.shape[2]
     if (quant is not None and quant.d_blocks == B
-            and -(-quant.codes.shape[1] // B) == db):
+            and quant.codes.shape[1] == D):
         return quant.scale.copy(), quant.zero.copy()
     scale = np.ones(B, np.float32)
     zero = np.zeros(B, np.float32)
-    rows = xs[valid[:, : xs.shape[1]]] if valid.size else xs.reshape(-1, xs.shape[2])
-    for b, (lo, hi) in enumerate(dim_block_bounds(scfg.dim, B)):
-        blk = rows[:, lo:min(hi, rows.shape[1])]
+    rows = xs[valid[:, : xs.shape[1]]] if valid.size else xs.reshape(-1, D)
+    for b, (lo, hi) in enumerate(dim_block_bounds(D, B)):
+        blk = rows[:, lo:hi]
         mn = float(blk.min()) if blk.size else 0.0
         mx = float(blk.max()) if blk.size else 0.0
         zero[b] = 0.5 * (mn + mx)
@@ -201,7 +204,8 @@ def build_query_arrays(
     quant_grid: Optional[Tuple[np.ndarray, np.ndarray]] = None,
 ):
     """Pack one query batch into the step's per-batch arrays, padded to the
-    static ``scfg.qb`` shape.
+    static ``scfg.qb`` shape, each dimension block laid out at ``scfg.db``
+    columns as the corpus is (:func:`build_corpus_arrays`).
 
       queries    [QB, D_pad]          f32 | int8 codes   (dims→model)
       probes     [QB, P]              i32   (replicated)
@@ -209,21 +213,17 @@ def build_query_arrays(
 
     With ``precision="int8"``, queries are encoded on the corpus's grid
     (``quant_grid`` = (scale [B], zero [B]) of the resident codes) —
-    out-of-range query values clip, padded rows/dims encode literal 0.0
-    exactly like the corpus padding, so padding cancels in the quantized
-    difference."""
+    out-of-range query values clip; padded rows and dims are code 0, as
+    in the corpus, so padding adds nothing to the quantized distance."""
     qb, D = scfg.qb, scfg.dim
-    queries = np.zeros((qb, D), np.float32)
     nq = min(q.shape[0], qb)
-    queries[:nq, : q.shape[1]] = q[:nq]
+    q = np.asarray(q[:nq], np.float32)
     if scfg.precision == "int8":
         assert quant_grid is not None, "int8 queries need the corpus grid"
-        scale, zero = quant_grid
-        codes = np.empty((qb, D), np.int8)
-        for b, (lo, hi) in enumerate(dim_block_bounds(D, scfg.d_blocks)):
-            c = np.rint((queries[:, lo:hi] - zero[b]) / scale[b])
-            codes[:, lo:hi] = np.clip(c, -127, 127).astype(np.int8)
-        queries = codes
+        queries = pack_dim_blocks(np.zeros((qb, D), np.int8),
+                                  encode_blocks(q, *quant_grid), scfg.d_blocks)
+    else:
+        queries = pack_dim_blocks(np.zeros((qb, D), np.float32), q, scfg.d_blocks)
     probes_pad = np.zeros((qb, probes.shape[1]), np.int32)
     probes_pad[:nq] = probes[:nq]
     probes_pad[nq:] = -2                      # match nothing

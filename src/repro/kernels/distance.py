@@ -19,6 +19,14 @@ distance computation"), adapted to the TPU memory hierarchy:
 
 Grid: (m_tiles, n_tiles, k_chunks); the k axis is minor-most so the output
 tile is revisited across the contraction and stays resident in VMEM.
+
+Padding: operands are padded up to tile multiples here (``pad_to``), which
+on the served path would copy every chunk of every batch. The serving
+executor therefore packs each dimension block of the resident corpus and
+of the queries once, at a multiple of ``tile_k``
+(``repro.core.index.padded_block_width``): at D = 960 a block is 1,024
+columns, 8 contraction steps, and the column padding here is a no-op.
+Zero columns change no norm and no product.
 """
 
 from __future__ import annotations

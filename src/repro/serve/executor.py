@@ -11,8 +11,9 @@ between chunks — end to end on the device mesh.
 Design:
 
 * **Corpus residency** — the sharded corpus, per-block norms, cluster ids
-  and row ids are packed once (:func:`repro.core.pipeline.build_corpus_arrays`)
-  and ``device_put`` on the mesh at construction. Serving a batch moves
+  and row ids are packed once (:func:`repro.core.pipeline.build_corpus_arrays`),
+  each dimension block at a multiple of the distance kernel's ``tile_k``
+  columns, and ``device_put`` on the mesh at construction. Serving a batch moves
   only the query block, probe table, τ seeds, and a small int32 row-index
   table host→device; the corpus never re-crosses the PCIe/ICI boundary.
 * **Cold tier** (``tier="host"``) — for host-resident (demoted) segments
@@ -57,7 +58,12 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.compat import shard_map_compat
-from repro.core.index import IVFIndex, assign_queries, preassign
+from repro.core.index import (
+    IVFIndex,
+    assign_queries,
+    padded_block_width,
+    preassign,
+)
 from repro.core.pipeline import (
     SpmdConfig,
     build_corpus_arrays,
@@ -158,7 +164,9 @@ class SpmdExecutor:
         # chunk-aligned
         self.corpus = preassign(index, plan, pad_to=self.cfg.chunk)
         self.cap_full = self.corpus.cap
-        dim_pad = -(-index.dim // B) * B
+        # each dimension block is packed once at a multiple of the
+        # kernel's contraction tile, so no chunk is padded in the step
+        dim_pad = B * padded_block_width(index.dim, B, self.cfg.tile_k)
         self._base_scfg = SpmdConfig(
             v_shards=V,
             d_blocks=B,
@@ -759,6 +767,9 @@ class SpmdExecutor:
                 f"qb{qb}_cap{cap}_k{k}_p{p}": n
                 for (qb, cap, k, p), n in sorted(self.trace_counts.items())
             },
+            "dim": self.index.dim,
+            "dim_padded": self._base_scfg.db,
+            "contraction_steps": self._base_scfg.db // self.cfg.tile_k,
             "qb_buckets": list(self.qb_buckets),
             "cap_buckets": list(self.cap_buckets),
             "tile_skipped": self.tile_skipped,

@@ -4,8 +4,8 @@ Each span is a ``jax.profiler.TraceAnnotation`` named ``harmony.<name>``: it
 lands on the profiler's host plane, on the same clock as the device
 planes, and nests by thread (``frontend.batch`` ⊃ ``engine`` ⊃
 ``executor`` ⊃ ``executor.*``). With no profiler running a span costs
-about a microsecond; spans open once per batch, never per request,
-cluster or chunk. ``docs/ARCHITECTURE.md`` lists them and how to capture
+about a microsecond; spans open once per batch (``index.kmeans`` once
+per index build), never per request, cluster or chunk. ``docs/ARCHITECTURE.md`` lists them and how to capture
 them.
 """
 

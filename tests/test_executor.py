@@ -97,6 +97,49 @@ def test_topk_pass_counter_same_on_pallas_and_jnp_paths(anns):
     np.testing.assert_array_equal(res_p.ids, res_j.ids)
 
 
+@pytest.fixture(scope="module", params=[960, 96], ids=lambda d: f"d{d}")
+def unaligned(request):
+    """A corpus whose width is no multiple of the 128-column contraction
+    tile (GIST's 960, and one narrower than a tile)."""
+    dim = request.param
+    ds = make_dataset(nb=4096, dim=dim, n_components=8, spread=0.6, seed=4)
+    cfg = HarmonyConfig(dim=dim, nlist=16, nprobe=4, topk=5, kmeans_iters=3)
+    return build_ivf(ds.x, cfg), make_queries(ds, nq=8, seed=5)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["jnp", "pallas"])
+def test_unaligned_width_is_padded_once_and_matches_oracle(unaligned, use_pallas,
+                                                           monkeypatch):
+    """Each dimension block is packed once at a multiple of tile_k: the
+    resident corpus and the queries arrive tile-aligned, the kernel pads no
+    column of any chunk, and the answers are the oracle's."""
+    from repro.kernels import distance
+
+    index, q = unaligned
+    column_pads, padded = [], []
+    pad_to = distance.pad_to
+
+    def recording_pad_to(a, mult, value):
+        padded.append(a.shape)
+        if a.ndim == 2 and a.shape[1] % mult[1]:
+            column_pads.append(a.shape)
+        return pad_to(a, mult, value)
+
+    monkeypatch.setattr(distance, "pad_to", recording_pad_to)
+    # tiles of this test alone, so the kernel is traced here, with the
+    # recording pad_to in place
+    ex = _executor(index, use_pallas=use_pallas, tile_m=16, tile_n=128)
+    res = ex.search_batch(q)
+    assert_matches_oracle(res, search_oracle(index, q))
+    steps = -(-index.dim // 128)
+    summary = ex.stats_summary()
+    assert (summary["dim"], summary["dim_padded"], summary["contraction_steps"]) \
+        == (index.dim, 128 * steps, steps)
+    assert ex._resident[0].shape[-1] == 128 * steps
+    assert padded and column_pads == []
+    assert res.stats["tile_total"] > 0
+
+
 def test_parity_metric_ip():
     ds = make_dataset(nb=3000, dim=24, n_components=6, spread=0.6, seed=2)
     cfg = HarmonyConfig(dim=24, nlist=24, nprobe=5, topk=5, kmeans_iters=4,

@@ -97,3 +97,25 @@ def test_each_batch_carries_the_span_tree_and_the_row_counters(precision, tmp_pa
 
 def test_the_benchmark_reads_the_program_prefix():
     assert PREFIX == "harmony." == spantrace.PREFIX
+
+
+def test_index_build_carries_the_kmeans_span(tmp_path):
+    """``harmony.index.kmeans`` wraps training, with the rows, the lists
+    and the row blocks of each Lloyd pass, as ``build_times`` records."""
+    x = np.random.default_rng(5).standard_normal((1500, 16)).astype(np.float32)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        index = build_ivf(x, HarmonyConfig(dim=16, nlist=12, nprobe=4,
+                                           kmeans_iters=2))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    from jax.profiler import ProfileData
+
+    spans = [s for s in spantrace.events(ProfileData.from_file(path))["spans"]
+             if s[0] == "index.kmeans"]
+    assert len(spans) == 1
+    args = {k: int(v) for k, v in spans[0][1].items()}
+    assert args == {"rows": 1500, "nlist": 12, "blocks": 1}
+    assert index.build_times["kmeans_blocks"] == 1
+    assert spans[0][3] / 1e9 <= index.build_times["train"]

@@ -9,6 +9,8 @@ import, so every xdist worker collects the same tests and only the
 worker that runs this file loads the TPU compiler.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -60,22 +62,30 @@ def _compile(fn, *shapes):
     return compiled.memory_analysis()
 
 
+def _distance_kernel(one_chip, kernel, d):
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    f32, i8 = jnp.float32, jnp.int8
+    if kernel == "fp32":
+        return _compile(
+            lambda *a: partial_distance_update(*a, interpret=False),
+            S((N, d), f32), S((N,), f32), S((M, d), f32), S((M,), f32),
+            S((M, N), f32), S((M,), f32))
+    return _compile(
+        lambda *a: int8_partial_distance_update(*a, interpret=False),
+        S((N, d), i8), S((N,), f32), S((M, d), i8), S((M,), f32),
+        S((), f32), S((M, N), f32), S((M,), f32))
+
+
 @pytest.mark.parametrize("kernel", ["fp32", "int8", "topk10", "topk40"])
 def test_kernel_compiles_for_v5e(one_chip, kernel):
     def S(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    f32, i32, i8 = jnp.float32, jnp.int32, jnp.int8
-    if kernel == "fp32":
-        mem = _compile(
-            lambda *a: partial_distance_update(*a, interpret=False),
-            S((N, D), f32), S((N,), f32), S((M, D), f32), S((M,), f32),
-            S((M, N), f32), S((M,), f32))
-    elif kernel == "int8":
-        mem = _compile(
-            lambda *a: int8_partial_distance_update(*a, interpret=False),
-            S((N, D), i8), S((N,), f32), S((M, D), i8), S((M,), f32),
-            S((), f32), S((M, N), f32), S((M,), f32))
+    f32, i32 = jnp.float32, jnp.int32
+    if kernel in ("fp32", "int8"):
+        mem = _distance_kernel(one_chip, kernel, D)
     else:
         k = int(kernel[4:])
         mem = _compile(
@@ -84,16 +94,23 @@ def test_kernel_compiles_for_v5e(one_chip, kernel):
     assert mem.argument_size_in_bytes > 0
 
 
-@pytest.mark.parametrize("precision", ["fp32", "int8"])
-def test_executor_step_compiles_for_one_v5e_chip(topo, monkeypatch,
-                                                 precision):
+@pytest.mark.parametrize("kernel", ["fp32", "int8"])
+def test_distance_kernel_compiles_for_v5e_at_960_dims(one_chip, kernel):
+    """GIST's width: 960 columns, padded to 1,024, eight contraction steps
+    of the (m, n, k) grid, the output tile resident across them."""
+    mem = _distance_kernel(one_chip, kernel, 960)
+    assert mem.argument_size_in_bytes >= N * 960 * (4 if kernel == "fp32" else 1)
+
+
+def _executor_step(topo, monkeypatch, precision, dim):
     """The jitted shard_map step, built by the executor's own code, for
-    a 1x1 mesh of a described chip at the sift-1M serving bucket: 1M
-    resident rows, 32 queries, 512K gathered candidates, 16 probes."""
+    a 1x1 mesh of a described chip at the 1M-row serving bucket: 1M
+    resident rows, 32 queries, 512K gathered candidates, 16 probes.
+    Returns (memory analysis, optimized HLO, padded width)."""
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)   # Mosaic, not interpret
     rng = np.random.default_rng(0)
-    x = rng.standard_normal((1024, D)).astype(np.float32)
-    index = build_ivf(x, HarmonyConfig(dim=D, nlist=8, nprobe=4,
+    x = rng.standard_normal((1024, dim)).astype(np.float32)
+    index = build_ivf(x, HarmonyConfig(dim=dim, nlist=8, nprobe=4,
                                        kmeans_iters=2))
     ex = SpmdExecutor(index, ExecutorConfig(use_pallas=True,
                                             precision=precision))
@@ -105,23 +122,44 @@ def test_executor_step_compiles_for_one_v5e_chip(topo, monkeypatch,
     qb, cap, nprobe = 32, 1 << 19, 16
     key = (qb, cap, k, nprobe)
     step = ex._make_step(ex._bucket_cfg(*key), key)
+    dp = ex._base_scfg.dim
 
     def S(shape, dt, *spec):
         return jax.ShapeDtypeStruct(shape, dt,
                                     sharding=NamedSharding(ex.mesh, P(*spec)))
 
     xdt = jnp.int8 if precision == "int8" else jnp.float32
-    args = [S((1, cap_full, D), xdt, "data", None, "model"),
+    args = [S((1, cap_full, dp), xdt, "data", None, "model"),
             S((1, 1, cap_full), jnp.float32, "model", "data", None),
             S((1, cap_full), jnp.int32, "data", None),
             S((1, cap_full), jnp.int32, "data", None)]
     if precision == "int8":
         args.append(S((1,), jnp.float32, "model"))
     args += [S((1, cap), jnp.int32, "data", None),
-             S((qb, D), xdt, None, "model"),
+             S((qb, dp), xdt, None, "model"),
              S((qb, nprobe), jnp.int32, None, None),
              S((qb,), jnp.float32, None)]
-    mem = _compile(step, *args)
+    compiled = jax.jit(step).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    assert mem.argument_size_in_bytes >= cap_full * D * jnp.dtype(xdt).itemsize
+    assert mem.argument_size_in_bytes >= cap_full * dp * jnp.dtype(xdt).itemsize
     assert used < HBM_BYTES, used
+    return mem, text, dp
+
+
+@pytest.mark.parametrize("precision", ["fp32", "int8"])
+def test_executor_step_compiles_for_one_v5e_chip(topo, monkeypatch,
+                                                 precision):
+    _executor_step(topo, monkeypatch, precision, D)
+
+
+def test_executor_step_compiles_for_one_v5e_chip_at_960_dims(topo, monkeypatch):
+    """The GIST1M bucket: the resident corpus is packed at 1,024 columns,
+    so the step pads no 256-row corpus chunk (the parent padded every
+    chunk, [256, 960] -> [256, 1024], inside the chunk loop)."""
+    mem, text, dp = _executor_step(topo, monkeypatch, "fp32", 960)
+    assert dp == 1024
+    chunk_pads = re.findall(r"= f32\[256,1024\]\S* pad\(", text)
+    assert chunk_pads == []
