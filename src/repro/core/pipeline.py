@@ -10,8 +10,9 @@ against the group's travelling τ, and forwards. After B stages every
 group has visited every dimension block. ``offset_v`` staggers ring
 starts across shards (the paper's load-aware deferred-block schedule).
 
-Billion-scale feasibility: a shard's rows are streamed in chunks
-(``lax.scan``), each chunk running one full dimension ring; a per-group
+Billion-scale feasibility: a shard's rows are streamed in chunks read in
+place through a chunk table (``lax.fori_loop`` over the table's live
+chunks), each chunk running one full dimension ring; a per-group
 running top-K (and its τ = kth best) tightens between chunks — the
 vector-level pipeline of Fig. 5(a). Accumulator memory is O(QG × chunk),
 not O(QG × cap).
@@ -339,27 +340,17 @@ def _score_chunk_update_int8(scfg: SpmdConfig, x_c, xn2_c, qrows, qn2, s2,
     return out, skip.sum(), skip.size
 
 
-def gather_local_candidates(rows, x_blk, xn2_blk, cluster_ids, row_ids):
-    """Device-side gather of probed-cluster candidates into a padded static
-    buffer (the serving executor's per-batch candidate set).
-
-    ``rows`` [cap_b] int32 indexes this shard's resident rows; -1 = pad.
-    Pad slots re-read row 0 but get cluster id -1, so they match no probe
-    and their accumulator stays +inf (excluded exactly like corpus padding).
-    """
-    cap_full = x_blk.shape[0]
-    keep = rows >= 0
-    safe = jnp.clip(rows, 0, cap_full - 1)
-    x_c = jnp.take(x_blk, safe, axis=0)
-    xn2_c = jnp.where(keep, jnp.take(xn2_blk, safe, axis=0), 0.0)
-    cl_c = jnp.where(keep, jnp.take(cluster_ids, safe, axis=0), -1)
-    id_c = jnp.where(keep, jnp.take(row_ids, safe, axis=0), -1)
-    return x_c, xn2_c, cl_c, id_c
+def identity_chunk_table(cluster_ids, chunk: int):
+    """The chunk table of an already-packed candidate buffer: chunk c
+    starts at row ``c * chunk``, and a row is live unless it is padding
+    (cluster id -1). Returns ``(starts [n], live [n, chunk])``."""
+    n = cluster_ids.shape[0] // chunk
+    starts = jnp.arange(n, dtype=jnp.int32) * chunk
+    return starts, (cluster_ids >= 0).reshape(n, chunk)
 
 
 def gather_host_candidates(arrays: dict, rows: np.ndarray) -> dict:
-    """Host-side analogue of :func:`gather_local_candidates` for
-    host-tier (cold) segments: gather the probed clusters' rows out of
+    """Host-tier (cold) segments: gather the probed clusters' rows out of
     the host-resident packed corpus into per-batch candidate arrays
     ready to stream to the mesh.
 
@@ -367,8 +358,8 @@ def gather_host_candidates(arrays: dict, rows: np.ndarray) -> dict:
     (int8 codes stream 4× less PCIe traffic than fp32 rows — the cold
     tier's preferred precision); ``rows`` [V, cap_b] int32 indexes each
     shard's packed rows, -1 = pad. Pad slots re-read row 0 but get
-    cluster id -1 and zero norms, so — exactly like the device-side
-    gather — they match no probe and never enter a top-K.
+    cluster id -1 and zero norms, so they match no probe and never enter
+    a top-K.
 
     Returns ``dict(x_c [V, cap_b, D], xn2_c [B, V, cap_b],
     cl_c [V, cap_b], id_c [V, cap_b])`` with the same dtypes, block
@@ -389,15 +380,25 @@ def gather_host_candidates(arrays: dict, rows: np.ndarray) -> dict:
 
 
 def ring_chunk_search(scfg: SpmdConfig, x_blk, xn2_blk, cluster_ids, row_ids,
-                      q_blk, probes, tau0, scale2=None):
+                      q_blk, probes, tau0, starts, live, n_iter, scale2=None):
     """Per-device ring search core (call under shard_map).
 
     Inputs are this device's local, already-squeezed arrays:
-      x_blk [cap, db], xn2_blk [cap], cluster_ids/row_ids [cap],
-      q_blk [qb, db], probes [qb, P], tau0 [qb].
+      x_blk [rows, db], xn2_blk [rows], cluster_ids/row_ids [rows],
+      q_blk [qb, db], probes [qb, P], tau0 [qb],
+    and the chunk table that says which of those rows to scan:
+      starts [n_b] i32 (chunk c is rows ``starts[c] : starts[c] + chunk``),
+      live [n_b, chunk] bool (the rows of chunk c to score), and
+      n_iter, the chunks to run (≤ n_b; may be traced).
+    The rows are read in place: a chunk is a slice of ``x_blk``, so the
+    resident corpus is scanned without a per-batch copy, and chunks past
+    ``n_iter`` cost nothing. A row scores where it is live and its
+    cluster is among the query's probes; every other row stays +inf.
     Runs the chunked dimension-ring scan (Pallas partial-distance with
     tile-granular early-stop, ppermute rotation, running top-K with τ
     tightening between chunks) and merges results across the mesh axes.
+    ``n_iter`` must agree across the mesh, so the ring's ppermutes stay
+    in step.
     Returns replicated (scores [qb, K], ids [qb, K], stats [4]): the
     distance tiles skipped and scored, the top-K insertion passes run
     (``kops.topk_pass_counts``, summed) and their most (chunks × query
@@ -411,7 +412,7 @@ def ring_chunk_search(scfg: SpmdConfig, x_blk, xn2_blk, cluster_ids, row_ids,
     the executor).
     """
     B, QG, K = scfg.d_blocks, scfg.qg, scfg.k
-    chunk, n_chunks = scfg.chunk, scfg.n_chunks
+    chunk = scfg.chunk
 
     b_idx = jax.lax.axis_index(scfg.axis_model)
     v_idx = jax.lax.axis_index(scfg.axis_data)
@@ -427,16 +428,18 @@ def ring_chunk_search(scfg: SpmdConfig, x_blk, xn2_blk, cluster_ids, row_ids,
 
     perm = [(i, (i + 1) % B) for i in range(B)]
 
-    def outer(carry, c):
+    def outer(c, carry):
         run_scores, run_ids, skip_cnt, tile_cnt, pass_cnt, slot_cnt = carry
-        row0 = c * chunk
+        row0 = starts[c]
         x_c = jax.lax.dynamic_slice_in_dim(x_blk, row0, chunk, 0)
         xn2_c = jax.lax.dynamic_slice_in_dim(xn2_blk, row0, chunk, 0)
         cl_c = jax.lax.dynamic_slice_in_dim(cluster_ids, row0, chunk, 0)
         id_c = jax.lax.dynamic_slice_in_dim(row_ids, row0, chunk, 0)
+        live_c = jax.lax.dynamic_index_in_dim(live, c, 0, keepdims=False)
 
-        # init acc for home group: 0 where probed, +inf otherwise
-        mask = (probes_home[:, :, None] == cl_c[None, None, :]).any(axis=1)
+        # init acc for home group: 0 where live and probed, +inf otherwise
+        mask = ((probes_home[:, :, None] == cl_c[None, None, :]).any(axis=1)
+                & live_c[None, :])
         tau_home = jnp.minimum(tau_home0, run_scores[:, -1])
         acc0 = jnp.where(mask, 0.0, jnp.inf).astype(jnp.float32)
 
@@ -485,14 +488,13 @@ def ring_chunk_search(scfg: SpmdConfig, x_blk, xn2_blk, cluster_ids, row_ids,
             run_scores = -neg
             run_ids = jnp.take_along_axis(cat_i, pos, axis=1)
         return (run_scores, run_ids, skip_cnt, tile_cnt, pass_cnt,
-                slot_cnt), None
+                slot_cnt)
 
     zero = jnp.int32(0)
-    (run_scores, run_ids, skip_cnt, tile_cnt, pass_cnt, slot_cnt), _ = (
-        jax.lax.scan(
-            outer,
+    run_scores, run_ids, skip_cnt, tile_cnt, pass_cnt, slot_cnt = (
+        jax.lax.fori_loop(
+            0, n_iter, outer,
             (run_scores0, run_ids0, zero, zero, zero, zero),
-            jnp.arange(n_chunks),
         )
     )
 
@@ -534,7 +536,8 @@ def ring_chunk_search(scfg: SpmdConfig, x_blk, xn2_blk, cluster_ids, row_ids,
 
 def make_device_fn(scfg: SpmdConfig):
     """The per-device body, to be wrapped in shard_map: squeeze the leading
-    sharded axes and run the ring search core over the full resident shard."""
+    sharded axes and run the ring search core over the full resident shard
+    (its identity chunk table: every chunk, in order)."""
 
     def device_fn(x_blk, xn2_blk, cluster_ids, row_ids, *rest):
         # shapes (per device):
@@ -548,9 +551,10 @@ def make_device_fn(scfg: SpmdConfig):
         cluster_ids = cluster_ids.reshape(scfg.cap)
         row_ids = row_ids.reshape(scfg.cap)
         q_blk = q_blk.reshape(scfg.qb, scfg.db)
+        starts, live = identity_chunk_table(cluster_ids, scfg.chunk)
         return ring_chunk_search(
             scfg, x_blk, xn2_blk, cluster_ids, row_ids, q_blk, probes, tau0,
-            scale2=scale2,
+            starts, live, scfg.n_chunks, scale2=scale2,
         )
 
     return device_fn

@@ -121,8 +121,14 @@ def prewarm_tau(
         np.arange(index.offsets[c], index.offsets[c] + take[c], dtype=np.int64)
         for c in range(index.nlist)
     ]
+    # a probe table may repeat a cluster (a filter duplicate-fills the
+    # slots of excluded ones): sample each cluster once, or a repeated
+    # row counts twice toward K and τ drops below the kth-best
+    probes = np.sort(probes, axis=1)
+    first = np.ones(probes.shape, bool)
+    first[:, 1:] = probes[:, 1:] != probes[:, :-1]
     all_rows = [
-        np.concatenate([sample_rows_per_cluster[c] for c in probes[i]])
+        np.concatenate([sample_rows_per_cluster[c] for c in probes[i][first[i]]])
         if probes.shape[1]
         else np.zeros((0,), np.int64)
         for i in range(nq)

@@ -69,7 +69,7 @@ def filter_excluded_rows(
     """Merge a filter's allowed bitmap with the tombstones into one
     *excluded* mask (bool [NB]) — a filter is just a per-query tombstone
     set, so the whole dead-row masking path (oracle member mask, host
-    engine's shard remap, executor's host-side gather) applies verbatim.
+    engine's shard remap, executor's chunk table) applies verbatim.
     Returns None when nothing is excluded (the unfiltered fast path)."""
     if flt is None:
         return dead_rows if dead_rows is not None and dead_rows.any() else None
@@ -94,7 +94,7 @@ def filtered_assign_queries(
     clusters than ``nprobe``) are *duplicate-filled* with the query's
     best live cluster instead of a sentinel: every downstream consumer
     (member-set assignment, τ prewarm's per-cluster sampling, the visit
-    schedule's ``np.unique``, the executor's row gather) treats
+    schedule's ``np.unique``, the executor's chunk table) treats
     duplicates as one probe, while a negative sentinel would wrap or
     crash them. Row-level masking stays the source of truth, so this is
     pure work avoidance — never a correctness dependency.

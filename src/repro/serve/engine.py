@@ -642,7 +642,7 @@ class HarmonyServer(DataPlane):
         per-query tombstone set): probe selection drops fully-excluded
         clusters (:func:`repro.core.search.filtered_assign_queries`), the
         engines mask filtered rows exactly like dead ones — on the spmd
-        backend inside the host-side gather, so the device work and the
+        backend in the executor's chunk table, so the device work and the
         (qb, cap) compile-cache keys are unchanged — and K never
         inflates. ``hybrid_text`` adds the BM25 lexical tier, fused with
         the vector top-k by reciprocal-rank fusion (scores then are

@@ -14,8 +14,8 @@ Design:
   and row ids are packed once (:func:`repro.core.pipeline.build_corpus_arrays`),
   each dimension block at a multiple of the distance kernel's ``tile_k``
   columns, and ``device_put`` on the mesh at construction. Serving a batch moves
-  only the query block, probe table, τ seeds, and a small int32 row-index
-  table host→device; the corpus never re-crosses the PCIe/ICI boundary.
+  only the query block, probe table, τ seeds, and a small chunk table
+  host→device; the corpus never re-crosses the PCIe/ICI boundary.
 * **Cold tier** (``tier="host"``) — for host-resident (demoted) segments
   nothing stays on the mesh: per batch, only the probed clusters' rows
   are gathered host-side (:func:`repro.core.pipeline.gather_host_candidates`)
@@ -24,13 +24,14 @@ Design:
   batch i+1's transfer while batch i's ring kernels run. int8 codes
   stream 4× less PCIe traffic than fp32 rows, and the fp32 re-rank reads
   host memory anyway, so the cold tier prefers the PR 6 quantized path.
-  Results are bit-identical to ``tier="device"``: same gathered
-  candidate set, same kernels, same bucket ladder.
-* **Candidate gather** — probed clusters are contiguous row ranges of the
-  resident shards (the IVF pack is cluster-sorted), so the host computes a
-  per-shard row-index union and the device gathers those rows into a
-  padded static candidate buffer (:func:`gather_local_candidates`). The
-  ring then scans ``cap_b`` gathered rows instead of the full shard.
+  Results are bit-identical to ``tier="device"``: the rows of the same
+  chunk table, slot for slot, the same kernels, the same bucket ladder.
+* **Chunk table** — probed clusters are contiguous row ranges of the
+  resident shards (the IVF pack is cluster-sorted), so the host cuts the
+  probed ranges into ``chunk``-row windows of the resident shard
+  (:class:`ChunkTable`: each window's first row and its live rows) and
+  the ring reads those windows in place, one per pass, for as many
+  passes as the table has chunks — no per-batch copy of the candidates.
 * **Static-shape bucketing** — jit recompiles per shape, and the
   scheduler's adaptive batches vary in both query count and candidate
   volume. Both are padded up a small ladder of (qb, cap) buckets; the
@@ -39,8 +40,10 @@ Design:
   bucket are split and merged host-side.
 
 Exactness: identical guarantees to the host engine and the oracle —
-padding adds rows whose cluster id is -1 (matches no probe) and queries
-whose τ is -inf (everything prunes), neither of which can enter a top-K.
+each live row of a probed list lies in exactly one chunk's live rows; a
+row that is not live, or whose cluster the query did not probe, starts
+at +inf, and padded queries carry τ = -inf (everything prunes), so
+neither can enter a top-K.
 Pruning is auto-disabled for ``metric="ip"`` (partial -dot sums are not
 monotone, so τ-pruning is only exact for L2).
 """
@@ -56,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
+from numpy.lib.stride_tricks import sliding_window_view
 
 from repro.compat import shard_map_compat
 from repro.core.index import (
@@ -70,7 +74,7 @@ from repro.core.pipeline import (
     build_query_arrays,
     corpus_shardings,
     gather_host_candidates,
-    gather_local_candidates,
+    identity_chunk_table,
     ring_chunk_search,
 )
 from repro.core.pruning import prewarm_tau
@@ -99,6 +103,39 @@ class ExecutorConfig:
     tile_n: int = 128
     tile_k: int = 128
     prune: Optional[bool] = None    # None → index.cfg.enable_pruning (L2 only)
+
+
+@dataclass(frozen=True)
+class ChunkTable:
+    """The rows one batch scans, per shard, as ``chunk``-row windows of
+    the resident shard: ``starts[v, c]`` is window c's first row,
+    ``live[v, c]`` marks the rows in it to score (inside a probed list,
+    not dead, and not owned by another window). Every shard runs
+    ``n_iter`` windows (the most any shard needs); the table is padded
+    to ``n_b`` windows on the cap ladder, and windows past a shard's own
+    are all dead."""
+
+    starts: np.ndarray      # [V, n_b] int32
+    live: np.ndarray        # [V, n_b, chunk] bool
+    n_iter: int
+    rows: int               # live rows of the table (probed, not dead)
+
+    @property
+    def cap(self) -> int:
+        """Rows of the table's bucket (n_b × chunk)."""
+        return self.live.shape[1] * self.live.shape[2]
+
+    @property
+    def chunks(self) -> int:
+        """Chunks the step scans, summed over the shards."""
+        return self.live.shape[0] * self.n_iter
+
+    def row_table(self) -> np.ndarray:
+        """[V, cap] resident row of each table slot, -1 where not live —
+        the host tier's gather list."""
+        rows = self.starts[:, :, None] + np.arange(self.live.shape[2],
+                                                   dtype=np.int32)
+        return np.where(self.live, rows, -1).reshape(self.live.shape[0], -1)
 
 
 def _pass_stats(passes: int, slots: int) -> dict:
@@ -164,6 +201,18 @@ class SpmdExecutor:
         # chunk-aligned
         self.corpus = preassign(index, plan, pad_to=self.cfg.chunk)
         self.cap_full = self.corpus.cap
+        # for the chunk table: each list's (shard, first row, end row) in
+        # the shard layout, and views [V, row, chunk] of the cluster and
+        # the packed row (-1: padding) of the chunk starting at each row
+        self._lists = np.asarray(
+            [self.corpus.cluster_slices[c] for c in range(index.nlist)],
+            np.int64).reshape(-1, 3).T
+        packed_of = np.full((V, self.cap_full), -1, np.int32)
+        packed_of[self.corpus.packed_shard, self.corpus.packed_row] = (
+            np.arange(index.nb, dtype=np.int32))
+        self._cluster_windows, self._packed_windows = (
+            sliding_window_view(a, self.cfg.chunk, axis=1)
+            for a in (self.corpus.cluster_shard, packed_of))
         # each dimension block is packed once at a multiple of the
         # kernel's contraction tile, so no chunk is padded in the step
         dim_pad = B * padded_block_width(index.dim, B, self.cfg.tile_k)
@@ -203,6 +252,12 @@ class SpmdExecutor:
         quant = index.int8_quant() if self.precision == "int8" else None
         arrays = build_corpus_arrays(self.corpus, self._base_scfg, quant=quant)
         self._quant_grid = arrays.pop("quant_grid", None)
+        # chunk starts are aligned down to the corpus dtype's row tile on
+        # TPU (8 rows of 32-bit data, 32 of int8), so a chunk read in
+        # place never cuts a packed tile
+        self.row_align = 32 // arrays["x_blocks"].dtype.itemsize
+        assert self.cfg.chunk % self.row_align == 0, (self.cfg.chunk,
+                                                      self.row_align)
         sh = corpus_shardings(self._base_scfg, self.mesh)
         names = ("x_blocks", "xn2_blocks", "cluster_ids", "row_ids")
         if self.precision == "int8":
@@ -230,8 +285,8 @@ class SpmdExecutor:
                 NamedSharding(self.mesh, P(ad, None)),       # id_c
             )
             # double-buffered prefetch queue: candidate uploads staged by
-            # the scheduler's formed-batch lookahead, keyed on the gather
-            # table so the later dispatch recognizes its own rows. Two
+            # the scheduler's formed-batch lookahead, keyed on the chunk
+            # table's rows so the later dispatch recognizes its own. Two
             # slots = the upload of batch i+1 in flight while batch i
             # computes; device_put is async, so the transfer genuinely
             # overlaps the ring kernels.
@@ -254,10 +309,11 @@ class SpmdExecutor:
         # (chunks × query tiles × K): their ratio is the merge's work share
         self.topk_passes = 0
         self.topk_pass_slots = 0
-        # live rows of the gather tables, and the padded rows the step
-        # scans (V × cap bucket): their ratio is the cap ladder's fill
+        # live rows of the chunk tables, and the rows of the chunks the
+        # step scans: their ratio is how full the chunks are
         self.rows_gathered = 0
         self.rows_scanned = 0
+        self.chunks_scanned = 0
         # cold-tier counters (always 0 for a device-tier executor)
         self.cold_dispatches = 0
         self.bytes_streamed = 0
@@ -303,17 +359,18 @@ class SpmdExecutor:
         """Inputs of the (qb, cap, k, nprobe) bucket step for one dummy
         query over one candidate row."""
         bscfg = self._bucket_cfg(qb, cap, k, nprobe)
-        rows = np.full((bscfg.v_shards, cap), -1, np.int32)
-        rows[:, 0] = 0
+        V, chunk = bscfg.v_shards, bscfg.chunk
+        live = np.zeros((V, cap // chunk, chunk), bool)
+        live[:, 0, 0] = True
+        table = ChunkTable(np.zeros((V, cap // chunk), np.int32), live, 1, V)
         qarr = build_query_arrays(
             np.zeros((1, self.index.dim), np.float32), bscfg,
             np.zeros((1, nprobe), np.int32),
             np.full((1,), np.inf, np.float32),
             quant_grid=self._quant_grid,
         )
-        cand = (self._upload_candidates(rows, cap)[0] if self.tier == "host"
-                else (*self._resident, rows))
-        return (*cand, qarr["queries"], qarr["probes"], qarr["tau0"])
+        return (*self._table_args(table)[0],
+                qarr["queries"], qarr["probes"], qarr["tau0"])
 
     # ----------------------------------------------------------- bucketing
     def _pick_bucket(self, ladder: Tuple[int, ...], need: int) -> int:
@@ -322,42 +379,70 @@ class SpmdExecutor:
                 return b
         return ladder[-1]
 
-    def _gather_rows(self, probes: np.ndarray,
-                     dead_rows: Optional[np.ndarray] = None):
-        """Per-shard union of probed clusters' resident row ranges, padded
-        to the smallest cap bucket. Returns (rows [V, cap_b] i32, cap_b,
-        live rows); (None, 0, 0) when the batch probes no resident rows.
+    def _chunk_table(self, probes: np.ndarray,
+                     dead_rows: Optional[np.ndarray] = None
+                     ) -> Optional[ChunkTable]:
+        """The batch's :class:`ChunkTable`; None when the batch probes no
+        live resident row.
 
-        ``dead_rows`` (bool [NB] over *packed* index rows — the mutable
-        data plane's tombstones) drops dead rows from the gather table, so
-        deletes cost zero device work and never inflate K: masking happens
-        in the host-side row union, the compiled step is untouched."""
-        V = self._base_scfg.v_shards
+        The probed lists of each shard, in packed order, merge into runs
+        where less than a chunk separates them; each run is cut into
+        ``chunk``-row windows from its first row aligned down to
+        ``row_align``; a window that would run past the shard's padded
+        rows reads from ``cap_full - chunk`` instead. A window owns the
+        rows from its nominal start on, so no row is scored twice, and
+        windows number at most ``cap_full / chunk`` a shard: the top of
+        the cap ladder holds any batch. A row is live where its cluster
+        is probed and it is not in ``dead_rows`` (bool [NB] over *packed*
+        index rows: the data plane's tombstones, or a filter's excluded
+        rows). Dead rows never inflate K and leave the compiled step as
+        it is; they do not split a run, and only a window with no live
+        row is dropped, so scattered dead rows still cost their windows'
+        device work."""
+        V, C = self._base_scfg.v_shards, self.cfg.chunk
         uniq = np.unique(probes) if probes.size else np.zeros(0, np.int64)
         uniq = uniq[uniq >= 0]
-        per_shard = [[] for _ in range(V)]
-        counts = np.zeros(V, np.int64)
-        for c in uniq:
-            v, lo, hi = self.corpus.cluster_slices[int(c)]
-            if hi > lo:
-                r = np.arange(lo, hi, dtype=np.int32)
-                if dead_rows is not None:
-                    # shard row lo+j of cluster c is packed row plo+j
-                    plo, phi = self.index.cluster_rows(int(c))
-                    r = r[~dead_rows[plo:phi]]
-                if r.size:
-                    per_shard[v].append(r)
-                    counts[v] += r.size
-        need = int(counts.max()) if len(uniq) else 0
-        if need == 0:
-            return None, 0, 0
-        cap_b = self._pick_bucket(self.cap_buckets, need)
-        rows = np.full((V, cap_b), -1, np.int32)
-        for v in range(V):
-            if per_shard[v]:
-                r = np.concatenate(per_shard[v])
-                rows[v, : len(r)] = r
-        return rows, cap_b, int(counts.sum())
+        lv, lo, hi = self._lists[:, uniq]
+        order = np.lexsort((lo, lv))
+        order = order[hi[order] > lo[order]]
+        lv, lo, hi = lv[order], lo[order], hi[order]
+        if lo.size == 0:
+            return None
+        # runs: lists of one shard closer than a chunk share windows
+        new = np.ones(lo.size, bool)
+        new[1:] = (lv[1:] != lv[:-1]) | (lo[1:] - hi[:-1] >= C)
+        r_at = np.flatnonzero(new)
+        r_a = lo[r_at] - lo[r_at] % self.row_align
+        r_n = -(-(np.maximum.reduceat(hi, r_at) - r_a) // C)
+        # windows: the nominal start (the rows it owns begin there) and
+        # the start it reads from, which stays inside the shard
+        c_run = np.repeat(np.arange(r_at.size), r_n)
+        c_nom = r_a[c_run] + C * (np.arange(c_run.size)
+                                  - (np.cumsum(r_n) - r_n)[c_run])
+        c_start = np.minimum(c_nom, self.cap_full - C)
+        c_v = lv[r_at][c_run]
+        probed = np.zeros(self.index.nlist + 1, bool)    # [-1]: padding
+        probed[uniq] = True
+        c_live = np.take(probed, self._cluster_windows[c_v, c_start])
+        for w in np.flatnonzero(c_start < c_nom):       # pulled back
+            c_live[w, : c_nom[w] - c_start[w]] = False
+        if dead_rows is not None:
+            dead = np.append(dead_rows, False)            # [-1]: padding
+            c_live &= ~np.take(dead, self._packed_windows[c_v, c_start])
+            keep = c_live.any(axis=1)
+            c_v, c_start, c_live = c_v[keep], c_start[keep], c_live[keep]
+        live_rows = int(np.count_nonzero(c_live))
+        if live_rows == 0:
+            return None
+        per_shard = np.bincount(c_v, minlength=V)
+        c_k = np.arange(c_v.size) - (np.cumsum(per_shard) - per_shard)[c_v]
+        n_iter = int(per_shard.max())
+        n_b = self._pick_bucket(self.cap_buckets, n_iter * C) // C
+        starts = np.zeros((V, n_b), np.int32)
+        starts[c_v, c_k] = c_start
+        live = np.zeros((V, n_b, C), bool)
+        live[c_v, c_k] = c_live
+        return ChunkTable(starts, live, n_iter, live_rows)
 
     # --------------------------------------------------------- compilation
     def _get_step(self, bscfg: SpmdConfig):
@@ -378,20 +463,15 @@ class SpmdExecutor:
             # this Python body runs only while jit traces → counts compiles
             counts[key] = counts.get(key, 0) + 1
             if int8:
-                scale2, rows, q_blk, probes, tau0 = rest
+                scale2, starts, live, n_iter, q_blk, probes, tau0 = rest
             else:
-                scale2, (rows, q_blk, probes, tau0) = None, rest
-            x_res = x_res.reshape(cap_full, db)
-            xn2_res = xn2_res.reshape(cap_full)
-            cl_res = cl_res.reshape(cap_full)
-            id_res = id_res.reshape(cap_full)
-            rows = rows.reshape(bscfg.cap)
-            q_blk = q_blk.reshape(bscfg.qb, db)
-            x_c, xn2_c, cl_c, id_c = gather_local_candidates(
-                rows, x_res, xn2_res, cl_res, id_res
-            )
+                scale2, (starts, live, n_iter, q_blk, probes, tau0) = None, rest
+            n_b = bscfg.n_chunks
             return ring_chunk_search(
-                bscfg, x_c, xn2_c, cl_c, id_c, q_blk, probes, tau0,
+                bscfg, x_res.reshape(cap_full, db), xn2_res.reshape(cap_full),
+                cl_res.reshape(cap_full), id_res.reshape(cap_full),
+                q_blk.reshape(bscfg.qb, db), probes, tau0,
+                starts.reshape(n_b), live.reshape(n_b, bscfg.chunk), n_iter,
                 scale2=scale2,
             )
 
@@ -405,7 +485,9 @@ class SpmdExecutor:
         if int8:
             resident_specs = resident_specs + (P(am),)   # scale2 (resident)
         in_specs = resident_specs + (
-            P(ad, None),            # rows (per-batch gather table)
+            P(ad, None),            # starts (per-batch chunk table)
+            P(ad, None, None),      # live
+            P(),                    # n_iter
             P(None, am),            # queries
             P(None, None),          # probes
             P(None),                # tau0
@@ -418,8 +500,9 @@ class SpmdExecutor:
 
     def _make_stream_step(self, bscfg: SpmdConfig, key):
         """Cold-tier step: the candidate arrays arrive *already gathered*
-        (host-side, :func:`gather_host_candidates`) and streamed to the
-        mesh, so the device body skips the resident gather and runs the
+        (host-side, :func:`gather_host_candidates`, slot for slot as the
+        chunk table lays them out) and streamed to the mesh, so the device
+        body scans them through the identity chunk table with the
         identical ring kernels over the same (qb, cap) bucket shapes —
         one compile cache, bit-identical results to the resident path."""
         db, counts = bscfg.db, self.trace_counts
@@ -428,17 +511,15 @@ class SpmdExecutor:
         def device_fn(x_c, xn2_c, cl_c, id_c, *rest):
             counts[key] = counts.get(key, 0) + 1
             if int8:
-                scale2, q_blk, probes, tau0 = rest
+                scale2, n_iter, q_blk, probes, tau0 = rest
             else:
-                scale2, (q_blk, probes, tau0) = None, rest
-            x_c = x_c.reshape(bscfg.cap, db)
-            xn2_c = xn2_c.reshape(bscfg.cap)
+                scale2, (n_iter, q_blk, probes, tau0) = None, rest
             cl_c = cl_c.reshape(bscfg.cap)
-            id_c = id_c.reshape(bscfg.cap)
-            q_blk = q_blk.reshape(bscfg.qb, db)
+            starts, live = identity_chunk_table(cl_c, bscfg.chunk)
             return ring_chunk_search(
-                bscfg, x_c, xn2_c, cl_c, id_c, q_blk, probes, tau0,
-                scale2=scale2,
+                bscfg, x_c.reshape(bscfg.cap, db), xn2_c.reshape(bscfg.cap),
+                cl_c, id_c.reshape(bscfg.cap), q_blk.reshape(bscfg.qb, db),
+                probes, tau0, starts, live, n_iter, scale2=scale2,
             )
 
         ad, am = bscfg.axis_data, bscfg.axis_model
@@ -451,6 +532,7 @@ class SpmdExecutor:
         if int8:
             cand_specs = cand_specs + (P(am),)   # scale2 (resident)
         in_specs = cand_specs + (
+            P(),                    # n_iter
             P(None, am),            # queries
             P(None, None),          # probes
             P(None),                # tau0
@@ -461,8 +543,21 @@ class SpmdExecutor:
         )
         return jax.jit(fn)
 
+    def _table_args(self, table: ChunkTable):
+        """The step's candidate operands for ``table``: the resident
+        arrays and the table, or (host tier) the streamed candidates.
+        Returns ``(operands, streamed bytes, prefetch hit)``."""
+        n_iter = np.int32(table.n_iter)
+        if self.tier != "host":
+            return (*self._resident, table.starts, table.live, n_iter), 0, 0
+        rows = table.row_table()
+        staged = self._prefetched.pop((rows.tobytes(), table.cap), None)
+        hit = int(staged is not None)
+        cand, nbytes = staged if hit else self._upload_candidates(rows)
+        return (*cand, n_iter), nbytes, hit
+
     # ---------------------------------------------------- cold-tier stream
-    def _upload_candidates(self, rows: np.ndarray, cap_b: int):
+    def _upload_candidates(self, rows: np.ndarray):
         """Gather the probed rows host-side and start their (async)
         upload. Returns ``(device_arrays, nbytes)`` — the arrays are
         valid step inputs immediately; the actual transfer overlaps
@@ -491,7 +586,7 @@ class SpmdExecutor:
         current batch computes (the scheduler calls this with its
         formed-batch lookahead). No-op on a device-tier executor.
 
-        The staged upload is keyed on the gather table itself, so the
+        The staged upload is keyed on the chunk table's rows, so the
         later :meth:`search_batch` recognizes its own candidate set no
         matter how the batch was predicted; a wrong prediction is just a
         miss (the dispatch uploads synchronously), never a wrong answer.
@@ -507,13 +602,14 @@ class SpmdExecutor:
             probes = assign_queries(self.index, queries, nprobe)
         max_qb = self.qb_buckets[-1]
         for lo in range(0, probes.shape[0], max_qb):
-            rows, cap_b, _ = self._gather_rows(probes[lo:lo + max_qb], dead_rows)
-            if cap_b == 0:
+            table = self._chunk_table(probes[lo:lo + max_qb], dead_rows)
+            if table is None:
                 continue
-            key = (rows.tobytes(), cap_b)
+            rows = table.row_table()
+            key = (rows.tobytes(), table.cap)
             if key in self._prefetched:
                 continue
-            self._prefetched[key] = self._upload_candidates(rows, cap_b)
+            self._prefetched[key] = self._upload_candidates(rows)
             self.prefetch_staged += 1
             while len(self._prefetched) > 2:    # double buffer: 2 slots
                 self._prefetched.pop(next(iter(self._prefetched)))
@@ -530,7 +626,7 @@ class SpmdExecutor:
         """Top-K for one batch through the device-resident pipeline.
 
         ``dead_rows`` applies the segmented data plane's tombstones (see
-        :meth:`_gather_rows`); the τ prewarm excludes the same rows so
+        :meth:`_chunk_table`); the τ prewarm excludes the same rows so
         pruning stays exact over the live set."""
         k = k or self.k
         queries = np.asarray(queries, np.float32)
@@ -580,7 +676,8 @@ class SpmdExecutor:
     def _search_bucket(self, queries, k, nprobe, probes, dead_rows,
                        span) -> SearchResult:
         """One dispatch of at most the largest qb bucket, inside the
-        ``executor`` span; ``span`` gets the bucket and the live rows."""
+        ``executor`` span; ``span`` gets the bucket, the live rows and
+        the chunks the step scans."""
         nq = queries.shape[0]
         t0 = time.perf_counter()
         if probes is None:
@@ -591,8 +688,8 @@ class SpmdExecutor:
             else:
                 probes = assign_queries(self.index, queries, nprobe)
         with spans.span("executor.gather_table"):
-            rows, cap_b, live = self._gather_rows(probes, dead_rows)
-        if cap_b == 0:
+            table = self._chunk_table(probes, dead_rows)
+        if table is None:
             dt = time.perf_counter() - t0
             self.dispatches += 1
             self.queries += nq
@@ -611,7 +708,9 @@ class SpmdExecutor:
                 },
             )
         qb_b = self._pick_bucket(self.qb_buckets, nq)
-        span.set_metadata(qb=qb_b, cap=cap_b, rows=live)
+        cap_b = table.cap
+        span.set_metadata(qb=qb_b, cap=cap_b, rows=table.rows,
+                          chunks=table.chunks)
         int8 = self.precision == "int8"
         # τ prewarm runs over the *original* probe table: prewarm_tau
         # indexes per-cluster sample rows, so pad columns (-2) must never
@@ -627,7 +726,6 @@ class SpmdExecutor:
             tau0 = np.full((nq,), np.inf, np.float32)
         k_step = min(k * self.cfg.rerank_factor, self.index.nb) if int8 else k
         compiles_before = self.compiles
-        cold_bytes, pf_hit = 0, 0
         with spans.span("executor.launch"):
             # compile-cache alignment: the step keys on probes.shape[1];
             # pad a narrower probe table (-2 columns match no cluster) up
@@ -644,26 +742,15 @@ class SpmdExecutor:
             qarr = build_query_arrays(queries, bscfg, probes, tau0,
                                       quant_grid=self._quant_grid)
             step = self._get_step(bscfg)
+            cand, cold_bytes, pf_hit = self._table_args(table)
             if self.tier == "host":
-                pkey = (rows.tobytes(), cap_b)
-                staged = self._prefetched.pop(pkey, None)
-                if staged is not None:
-                    cand, cold_bytes = staged
-                    pf_hit = 1
-                    self.prefetch_hits += 1
-                else:
-                    cand, cold_bytes = self._upload_candidates(rows, cap_b)
-                    self.prefetch_misses += 1
+                self.prefetch_hits += pf_hit
+                self.prefetch_misses += 1 - pf_hit
                 self.cold_dispatches += 1
                 self.bytes_streamed += cold_bytes
-                gs, gi, st = step(
-                    *cand, qarr["queries"], qarr["probes"], qarr["tau0"],
-                )
-            else:
-                gs, gi, st = step(
-                    *self._resident, rows,
-                    qarr["queries"], qarr["probes"], qarr["tau0"],
-                )
+            gs, gi, st = step(
+                *cand, qarr["queries"], qarr["probes"], qarr["tau0"],
+            )
         with spans.span("executor.wait"):
             scores = np.asarray(gs)[:nq]
             ids = np.asarray(gi)[:nq].astype(np.int64)
@@ -680,8 +767,9 @@ class SpmdExecutor:
         self.tile_total += int(st[1])
         self.topk_passes += int(st[2])
         self.topk_pass_slots += int(st[3])
-        self.rows_gathered += live
-        self.rows_scanned += rows.size
+        self.rows_gathered += table.rows
+        self.chunks_scanned += table.chunks
+        self.rows_scanned += table.chunks * self.cfg.chunk
         return SearchResult(
             ids=ids,
             scores=scores,
@@ -778,4 +866,5 @@ class SpmdExecutor:
             **_pass_stats(self.topk_passes, self.topk_pass_slots),
             "rows_gathered": self.rows_gathered,
             "rows_scanned": self.rows_scanned,
+            "chunks_scanned": self.chunks_scanned,
         }

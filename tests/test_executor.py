@@ -6,8 +6,15 @@ Everything runs on CPU — the jnp scoring path (use_pallas=False) plus one
 interpret-mode Pallas case keep the BlockSpec logic covered without a TPU.
 """
 
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
+
+import chunk_table_cases
 
 from repro.config import HarmonyConfig
 from repro.core import build_ivf, search_oracle
@@ -150,6 +157,28 @@ def test_parity_metric_ip():
     # -dot partial sums are not monotone → executor must not prune for ip
     assert ex.prune is False
     assert_matches_oracle(ex.search_batch(q), search_oracle(index, q))
+
+
+@pytest.mark.parametrize("mesh", ["1x1", "2x2"])
+@pytest.mark.parametrize("precision", ["fp32", "int8"])
+def test_chunk_table_scores_each_probed_row_once(precision, mesh):
+    """The step reads the probed lists in place through the chunk table:
+    merged runs, long lists, unaligned starts, a window pulled back at
+    the shard's end, tombstones, a filter and empty probe sets all give
+    the oracle's answers with no id twice (``chunk_table_cases``). The
+    2 × 2 mesh runs in a subprocess with four host devices."""
+    if mesh == "1x1":
+        chunk_table_cases.run(precision)
+        return
+    root = Path(__file__).resolve().parent.parent
+    env = dict(os.environ, PYTHONPATH=str(root / "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run(
+        [sys.executable, str(root / "tests" / "chunk_table_cases.py"),
+         precision, "2"],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "CHUNK_TABLE_OK" in proc.stdout, proc.stdout + proc.stderr
 
 
 # ------------------------------------------------------- bucketing edges

@@ -6,10 +6,12 @@ import pytest
 
 from repro.config import HarmonyConfig
 from repro.core import (
+    assign_queries,
     build_ivf,
     harmony_search,
     plan_search,
     preassign,
+    prewarm_tau,
     search_oracle,
 )
 from repro.data import make_dataset, make_queries
@@ -59,6 +61,20 @@ def test_pruning_is_exact(setup):
     _compare(off, on)
     # and pruning actually skipped work
     assert on.stats["pair_flops"] < off.stats["pair_flops"]
+
+
+def test_prewarm_samples_a_repeated_probe_once(setup):
+    """A probe table that repeats a cluster (a filter duplicate-fills the
+    slots of excluded clusters) gives the τ of the table without the
+    repeats: a sampled row counted twice toward K would pull τ below the
+    kth-best and prune a true answer."""
+    ds, cfg, index, q = setup
+    probes = assign_queries(index, q, 2)        # 2 × 4 samples < K = 10
+    rep = np.concatenate([probes, probes[:, :1].repeat(4, axis=1)], axis=1)
+    want = prewarm_tau(index, q, probes, cfg.topk, samples_per_cluster=4)
+    assert np.isinf(want).all()
+    np.testing.assert_array_equal(
+        prewarm_tau(index, q, rep, cfg.topk, samples_per_cluster=4), want)
 
 
 def test_pipeline_off_matches(setup):

@@ -18,7 +18,7 @@ from repro.serve import HarmonyServer, SchedulerConfig, ServingFrontend
 from repro.serve.executor import ExecutorConfig
 from repro.serve.spans import PREFIX
 
-NQ, BATCH = 24, 8
+NQ, BATCH, CHUNK = 24, 8, 128
 EXECUTOR_PARTS = {"executor.gather_table", "executor.launch", "executor.wait"}
 
 
@@ -28,7 +28,7 @@ def _served_trace(precision, tmp_path):
     index = build_ivf(x, HarmonyConfig(dim=16, nlist=16, nprobe=4, topk=5,
                                        kmeans_iters=2))
     srv = HarmonyServer(index, n_nodes=1, backend="spmd", precision=precision,
-                        executor_cfg=ExecutorConfig(qb_buckets=(BATCH,), chunk=128,
+                        executor_cfg=ExecutorConfig(qb_buckets=(BATCH,), chunk=CHUNK,
                                                     precision=precision))
     ex = srv.executor
     q = rng.standard_normal((NQ, 16)).astype(np.float32)
@@ -71,7 +71,7 @@ def test_each_batch_carries_the_span_tree_and_the_row_counters(precision, tmp_pa
     assert all(int(d[1]["queued"]) >= 0 for d in by_name["frontend.dispatch"])
     own = EXECUTOR_PARTS | ({"executor.prewarm"} if precision == "fp32"
                             else {"executor.rerank"})
-    live_rows = 0
+    live_rows = scanned_chunks = 0
     for fb in batches:
         bid = int(fb[1]["batch"])
         rows = [a.req_id for a in answers if a.batch_id == bid]
@@ -90,9 +90,14 @@ def test_each_batch_carries_the_span_tree_and_the_row_counters(precision, tmp_pa
         assert int(ex[1]["rows"]) == int(index.sizes[np.unique(probes)].sum())
         assert int(ex[1]["rows"]) <= int(ex[1]["cap"]) and int(ex[1]["qb"]) == BATCH
         live_rows += int(ex[1]["rows"])
+        # the step scans whole chunks of the table, at most the bucket
+        chunks = int(ex[1]["chunks"])
+        assert int(ex[1]["rows"]) <= chunks * CHUNK <= int(ex[1]["cap"])
+        scanned_chunks += chunks
     assert gathered == live_rows <= scanned
-    assert scanned == sum(int(s[1]["cap"]) for s in by_name["executor"])
+    assert scanned == scanned_chunks * CHUNK
     assert summary["rows_gathered"] >= gathered and summary["rows_scanned"] >= scanned
+    assert summary["rows_scanned"] == summary["chunks_scanned"] * CHUNK
 
 
 def test_the_benchmark_reads_the_program_prefix():

@@ -102,11 +102,25 @@ def test_distance_kernel_compiles_for_v5e_at_960_dims(one_chip, kernel):
     assert mem.argument_size_in_bytes >= N * 960 * (4 if kernel == "fp32" else 1)
 
 
+# XLA temporaries of the step that gathered the bucket's rows into a
+# copy (described-v5e compiles of that step, same shapes as below)
+GATHERING_STEP_TEMP_BYTES = {
+    ("fp32", 128): 276_154_368,
+    ("int8", 128): 72_335_360,
+    ("fp32", 960): 2_155_202_560,
+    ("int8", 960): 544_654_336,
+}
+_STEPS = {}
+
+
 def _executor_step(topo, monkeypatch, precision, dim):
     """The jitted shard_map step, built by the executor's own code, for
     a 1x1 mesh of a described chip at the 1M-row serving bucket: 1M
-    resident rows, 32 queries, 512K gathered candidates, 16 probes.
-    Returns (memory analysis, optimized HLO, padded width)."""
+    resident rows, 32 queries, a 2,048-chunk table (512K rows), 16 probes.
+    Returns (memory analysis, optimized HLO, padded width); compiled once
+    per (precision, dim) in this module."""
+    if (precision, dim) in _STEPS:
+        return _STEPS[precision, dim]
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)   # Mosaic, not interpret
     rng = np.random.default_rng(0)
     x = rng.standard_normal((1024, dim)).astype(np.float32)
@@ -135,7 +149,10 @@ def _executor_step(topo, monkeypatch, precision, dim):
             S((1, cap_full), jnp.int32, "data", None)]
     if precision == "int8":
         args.append(S((1,), jnp.float32, "model"))
-    args += [S((1, cap), jnp.int32, "data", None),
+    chunk = ex.cfg.chunk
+    args += [S((1, cap // chunk), jnp.int32, "data", None),
+             S((1, cap // chunk, chunk), jnp.bool_, "data", None, None),
+             S((), jnp.int32),
              S((qb, dp), xdt, None, "model"),
              S((qb, nprobe), jnp.int32, None, None),
              S((qb,), jnp.float32, None)]
@@ -146,7 +163,8 @@ def _executor_step(topo, monkeypatch, precision, dim):
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes >= cap_full * dp * jnp.dtype(xdt).itemsize
     assert used < HBM_BYTES, used
-    return mem, text, dp
+    _STEPS[precision, dim] = mem, text, dp
+    return _STEPS[precision, dim]
 
 
 @pytest.mark.parametrize("precision", ["fp32", "int8"])
@@ -163,3 +181,21 @@ def test_executor_step_compiles_for_one_v5e_chip_at_960_dims(topo, monkeypatch):
     assert dp == 1024
     chunk_pads = re.findall(r"= f32\[256,1024\]\S* pad\(", text)
     assert chunk_pads == []
+
+
+@pytest.mark.parametrize("precision,dim", sorted(GATHERING_STEP_TEMP_BYTES))
+def test_executor_step_reads_the_lists_in_place(topo, monkeypatch, precision,
+                                               dim):
+    """The step scans the resident corpus through the chunk table: no
+    gather of corpus rows, no [cap, D] array (a gathered copy, or the
+    fill select over one), and fewer temporaries than the gathering step.
+    Each chunk of corpus rows is made once, by the slice fusion that
+    reads it in place; no copy or relayout of it follows (int8 rows are
+    tiled 32 to a tile, and the chunk table aligns their starts so)."""
+    mem, text, dp = _executor_step(topo, monkeypatch, precision, dim)
+    assert re.findall(rf"\[\d+,{dp}\]\S* gather\(", text) == []
+    assert re.findall(rf"\[{1 << 19},{dp}\]", text) == []
+    assert mem.temp_size_in_bytes < GATHERING_STEP_TEMP_BYTES[precision, dim]
+    xdt = "s8" if precision == "int8" else "f32"
+    chunk_ops = re.findall(rf"= {xdt}\[256,{dp}\]\S* ([a-z-]+)\(", text)
+    assert sorted(chunk_ops) == ["dynamic-slice", "fusion"], chunk_ops
